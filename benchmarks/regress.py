@@ -230,7 +230,10 @@ def _extract_kernelcheck(b: dict) -> tuple:
     """Static analyzer report (repro/analysis/kernelcheck.py): per-kernel
     modelled VMEM fractions and analytic flop/byte bills. All numbers are
     deterministic functions of the code, so tolerances are tight — a
-    jump means a kernel's tiling or cost model actually changed."""
+    jump means a kernel's cost model or transient footprint changed. A
+    kernel re-tiled onto a different grid is a new design, not a drift:
+    the grid is part of its metric names, so its numbers start a new
+    series while the other kernels keep comparing."""
     shape = {op: [c["shapes"] for c in v.get("classes", [])]
              for op, v in b.get("kernels", {}).items()}
     metrics: Dict[str, dict] = {}
@@ -238,11 +241,11 @@ def _extract_kernelcheck(b: dict) -> tuple:
     for op, v in sorted(b.get("kernels", {}).items()):
         for i, c in enumerate(v.get("classes", [])):
             worst_frac = max(worst_frac, c["vmem_frac"])
-            metrics[f"{op}.c{i}.vmem_frac"] = _m(c["vmem_frac"], "lower",
-                                                 0.25)
-            metrics[f"{op}.c{i}.flops"] = _m(c["declared"]["flops"],
-                                             "lower", 0.5)
-            metrics[f"{op}.c{i}.hbm_bytes"] = _m(
+            cls = f"{op}.c{i}@{'x'.join(str(g) for g in c['grid'])}"
+            metrics[f"{cls}.vmem_frac"] = _m(c["vmem_frac"], "lower", 0.25)
+            metrics[f"{cls}.flops"] = _m(c["declared"]["flops"],
+                                         "lower", 0.5)
+            metrics[f"{cls}.hbm_bytes"] = _m(
                 c["declared"]["hbm_bytes"], "lower", 0.5)
     bounds = [
         _bound("kernelcheck_clean", b.get("clean") == 1,

@@ -31,6 +31,8 @@ MODULES = [
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="substring filter on module names")

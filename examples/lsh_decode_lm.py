@@ -14,12 +14,14 @@ import jax.numpy as jnp
 from repro.configs.base import get_config
 from repro.data.tokens import SyntheticCorpus
 from repro.launch import serve
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.train import TrainHParams, init_state, make_train_step
 from repro.models import lm_head
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_config("qwen3_0_6b").reduced()
     mesh = make_local_mesh()
     hp = TrainHParams(lr=1e-3, warmup=5, total_steps=30)

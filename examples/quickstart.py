@@ -16,9 +16,11 @@ from repro.core import topk
 from repro.core.bucket_index import build_bucket_index
 from repro.core.index import IndexSpec, build
 from repro.data.synthetic import make_dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ds = make_dataset("imagenet", jax.random.PRNGKey(0), n=20000,
                       num_queries=100)
     print(f"dataset: {ds.items.shape[0]} items, d={ds.items.shape[1]}")

@@ -29,10 +29,12 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.core import distributed, topk
 from repro.core.index import IndexSpec
 from repro.data.als import als_factorize, synthetic_ratings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 
 
 def main() -> None:
+    enable_compile_cache()
     # 1. train embeddings
     ratings, weights = synthetic_ratings(jax.random.PRNGKey(0),
                                          n_users=400, n_items=4000,

@@ -14,11 +14,13 @@ import argparse
 import dataclasses
 
 from repro.configs.base import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.train import TrainHParams, run_training
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_0_6b", choices=ARCH_IDS)
     ap.add_argument("--steps", type=int, default=300)

@@ -1,25 +1,12 @@
-"""Version-compat shims for the installed jax (see DESIGN.md §6).
+"""Runtime-configuration shims (see DESIGN.md §6).
 
-The codebase targets the modern jax API surface (``jax.shard_map``,
-``jax.set_mesh``, ``jax.sharding.AxisType``); older releases spell these
-differently or lack them. Everything version-sensitive funnels through here
-so call sites stay on the modern spelling.
+The one thing left here is the x64 probe: repro-lint rule R5 confines
+float64/x64 references to this module.
 """
 
 from __future__ import annotations
 
 import jax
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` (jax >= 0.6) or ``jax.experimental.shard_map``
-    (older, where ``check_vma`` is spelled ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
 
 
 def widest_float():
@@ -28,12 +15,3 @@ def widest_float():
     repro-lint rule R5 confines float64/x64 references to this module."""
     import jax.numpy as jnp
     return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict — older jax wraps the
-    per-device dict in a one-element list."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost

@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.bucket_index import build_bucket_index, rank_from_scores
@@ -61,6 +60,7 @@ from repro.core.engine import planned_take, range_cum_before, select_engine
 from repro.core.index import ComposedMultiTable, IndexSpec, _check_probe
 from repro.core.index import build as build_spec
 from repro.core.probe import DEFAULT_EPS
+from repro.core.topk import EXACT
 from repro.kernels import ops
 from repro.obs.trace import span_or_null
 from repro.obs.tracker import resolve_tracker
@@ -358,7 +358,7 @@ def _shard_query(q_codes, queries, params, dir_code, dir_rid, dir_size,
 
     slot_ok = jnp.arange(width, dtype=jnp.int32)[None, :] < total[:, None]
     cand = items[pos]                                         # (Q, P, d)
-    ip = jnp.einsum("qd,qpd->qp", queries, cand)
+    ip = jnp.einsum("qd,qpd->qp", queries, cand, precision=EXACT)
     ip = jnp.where(slot_ok, ip, -jnp.inf)
     if width < k:        # a shard smaller than k still merges cleanly
         ip = jnp.concatenate(
@@ -433,6 +433,14 @@ class DistributedEngine:
         self.query_axis = query_axis
         self.family = index.spec.resolve_family()
         self.tracker = resolve_tracker(tracker)
+        # the params are replicated over the mesh, so the encode is a
+        # program over every device; the TPU kernel compiler cannot
+        # partition a kernel, so each device encodes the whole batch
+        self._encode = jax.jit(jax.shard_map(
+            functools.partial(self.family.encode_queries, impl=self.impl),
+            mesh=mesh,
+            in_specs=(jax.tree.map(lambda _: P(), index.params), P()),
+            out_specs=P(), check_vma=False))
         self._mapped_cache = {}
         self._range_counts_cache = None
 
@@ -474,7 +482,7 @@ class DistributedEngine:
         row = P(self.axis)
         row2 = P(self.axis, None)
         params_spec = jax.tree.map(lambda _: P(), idx.params)
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(q2, q2, params_spec, P(), P(), P(), P(), P(), P(),
                       row2, row2, row, row, row, row, row),
@@ -530,8 +538,7 @@ class DistributedEngine:
             num_probe = _check_probe(num_probe, k, idx.num_items)
         tr = self.tracker
         with span_or_null(tr, "repro.engine.hash_encode") as sp:
-            q_codes = sp.sync(self.family.encode_queries(
-                idx.params, queries, impl=self.impl))
+            q_codes = sp.sync(self._encode(idx.params, queries))
         mapped = self._mapped(num_probe, int(k), budgets)
         # NOTE: re-rank uses the ORIGINAL queries (true inner products);
         # the family transform only affects the hash codes.

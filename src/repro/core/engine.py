@@ -446,6 +446,13 @@ class QueryEngine:
         if quantized and engine != "fused":
             raise ValueError("quantized phase-1 scoring is a fused-engine "
                              "arm; pass engine=\"fused\"")
+        if engine == "fused" and impl != "ref" \
+                and jax.default_backend() == "tpu":
+            raise NotImplementedError(
+                "engine=\"fused\" does not compile for TPU: its kernel "
+                "gathers candidate rows in-kernel and keeps the whole item "
+                "payload resident in VMEM (DESIGN.md §17). Use "
+                "engine=\"bucket\" or \"dense\", or impl=\"ref\".")
         if buckets is None:
             buckets = build_bucket_index(index)
         if engine == "auto":

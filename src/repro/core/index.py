@@ -45,7 +45,7 @@ from repro.core import hashing
 from repro.core.family import FAMILY_NAMES, HashFamily, get_family
 from repro.core.partition import (effective_upper, partition_by_scheme)
 from repro.core.probe import DEFAULT_EPS
-from repro.core.topk import rerank
+from repro.core.topk import EXACT, rerank
 
 SCHEMES = ("percentile", "uniform")
 ENGINES = ("auto", "dense", "bucket", "fused")
@@ -409,7 +409,7 @@ class ComposedMultiTable(NamedTuple):
         top_scores = jnp.take_along_axis(scores, top, axis=1)
         cand_vec = self.items[top]                        # (Q, C, d)
         ip = jnp.einsum("qd,qcd->qc", queries.astype(jnp.float32),
-                        cand_vec.astype(jnp.float32))
+                        cand_vec.astype(jnp.float32), precision=EXACT)
         ip = jnp.where(top_scores > 0, ip, -jnp.inf)
         vals, pos = jax.lax.top_k(ip, k)
         ids = jnp.take_along_axis(top, pos, axis=1)

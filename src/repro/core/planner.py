@@ -164,16 +164,22 @@ def calibrate_from_order(order_ids: np.ndarray, range_id: np.ndarray,
     gpos = np.empty((q, n), np.int64)
     rows = np.arange(q)[:, None]
     gpos[rows, order_ids] = np.arange(n, dtype=np.int64)[None, :]
-    sorted_rid = range_id[order_ids]                         # (Q, N)
+    t_gpos = np.take_along_axis(gpos, truth_ids, axis=1)          # (Q, k)
+    # a stable sort of each probe-ordered row by range (numpy radix-sorts
+    # 16-bit keys in linear time) lists every range's items in probe
+    # order; an item's place within its range's group is its position
+    key_t = np.int16 if m <= np.iinfo(np.int16).max else np.int64
+    sorted_rid = range_id[order_ids].astype(key_t)           # (Q, N)
+    by_range = np.argsort(sorted_rid, axis=1, kind="stable")
+    rid_by = np.take_along_axis(sorted_rid, by_range, axis=1)
+    idx = np.arange(n, dtype=np.int64)[None, :]
+    first = np.ones((q, n), bool)
+    first[:, 1:] = rid_by[:, 1:] != rid_by[:, :-1]
+    group_start = np.maximum.accumulate(np.where(first, idx, 0), axis=1)
     wpos_sorted = np.empty((q, n), np.int64)
-    for j in range(m):
-        mask = sorted_rid == j
-        wpos_sorted[mask] = (np.cumsum(mask, axis=1) - 1)[mask]
-    wpos = np.empty((q, n), np.int64)
-    wpos[rows, order_ids] = wpos_sorted
-
-    t_gpos = np.take_along_axis(gpos, truth_ids, axis=1).reshape(-1)
-    t_wpos = np.take_along_axis(wpos, truth_ids, axis=1).reshape(-1)
+    wpos_sorted[rows, by_range] = idx - group_start
+    t_wpos = np.take_along_axis(wpos_sorted, t_gpos, axis=1).reshape(-1)
+    t_gpos = t_gpos.reshape(-1)
     t_rid = range_id[truth_ids.reshape(-1)]
     total = t_rid.size
 
@@ -427,7 +433,8 @@ def adaptive_query(engine, queries: jax.Array, k: int, *,
         sl = jax.lax.dynamic_slice_in_dim(cand_p, c * chunk, chunk, axis=1)
         ok = jax.lax.dynamic_slice_in_dim(valid_p, c * chunk, chunk,
                                           axis=1)
-        ip = jnp.einsum("qd,qpd->qp", queries, items[sl])
+        ip = jnp.einsum("qd,qpd->qp", queries, items[sl],
+                        precision=topk.EXACT)
         ip = jnp.where(ok & active[:, None], ip, -jnp.inf)
         av = jnp.concatenate([vals, ip], axis=1)
         ai = jnp.concatenate([ids, sl], axis=1)

@@ -10,11 +10,15 @@ import jax.numpy as jnp
 from repro.obs import cost
 from repro.obs.trace import span_or_null
 
+# f32 products and sums throughout: the TPU's default matmul precision
+# rounds f32 inputs to bf16, which is not an exact inner product
+EXACT = jax.lax.Precision.HIGHEST
+
 
 def exact_mips(queries: jax.Array, items: jax.Array, k: int
                ) -> Tuple[jax.Array, jax.Array]:
     """Brute-force top-k MIPS: (Q, d) x (N, d) -> values (Q, k), ids (Q, k)."""
-    scores = queries @ items.T
+    scores = jnp.matmul(queries, items.T, precision=EXACT)
     return jax.lax.top_k(scores, k)
 
 
@@ -35,7 +39,8 @@ def rerank(queries: jax.Array, items: jax.Array, cand_ids: jax.Array, k: int,
     with span_or_null(tracker, "repro.engine.re_rank") as sp:
         sp.set_attrs(**cost.re_rank_cost(Q, P, queries.shape[1]))
         cand = items[cand_ids]                              # (Q, P, d)
-        scores = sp.sync(jnp.einsum("qd,qpd->qp", queries, cand))
+        scores = sp.sync(jnp.einsum("qd,qpd->qp", queries, cand,
+                                    precision=EXACT))
     with span_or_null(tracker, "repro.engine.top_k") as sp:
         sp.set_attrs(**cost.top_k_cost(Q, P, k))
         # first-occurrence duplicate mask without the (Q, P, P) blowup:

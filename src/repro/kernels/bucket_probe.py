@@ -30,15 +30,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.annotations import KernelAnnotation
+
+LANES = 128        # queries per gather block (the lane width)
+GATHER_BS = 1024   # runs per gather grid step
+GATHER_BP = 512    # probed slots per gather grid step
 
 # kernelcheck model claims (DESIGN.md §16). Both kernels partition their
 # output grid bijectively (no revisiting); both wrappers slice every padded
 # row/column off before returning. Transient peaks: the match kernel
 # broadcasts a (BQ, BB, W) XOR tile + int32 popcount tile and reduces to a
-# (BQ, BB) block; the gather kernel keeps ~4 (BQ, P) int32/bool masks live
-# inside its fori_loop body.
+# (BQ, BB) block; the gather kernel keeps its (BP, 128) loop carry, slot
+# iota and compare/select tile live, plus one reduced copy of the run
+# block for the skip tests. Its run grid dimension revisits the output
+# block on purpose (the sequential overwrite across run blocks).
 MATCH_ANNOTATION = KernelAnnotation(
     name="bucket_match",
     grid_names=("queries", "buckets"),
@@ -49,11 +56,14 @@ MATCH_ANNOTATION = KernelAnnotation(
 )
 GATHER_ANNOTATION = KernelAnnotation(
     name="bucket_gather",
-    grid_names=("queries",),
-    extra_vmem=lambda ins, outs: 4 * outs[0][0] * outs[0][1] * 4,
+    grid_names=("queries", "slots", "runs"),
+    revisit_dims=(2,),
+    extra_vmem=lambda ins, outs: (3 * outs[0][0] * outs[0][1] * 4
+                                  + ins[0][0] * ins[0][1] * 4),
     pad_contained=True,
-    note="padded query rows carry a single covering run [0, num_probe) so "
-         "the in-kernel CSR walk stays in-contract; rows are sliced off",
+    note="padded query lanes carry a single covering run and padded runs "
+         "start past every slot, so the walk stays in-contract; both are "
+         "sliced off",
 )
 
 
@@ -99,59 +109,84 @@ def bucket_match_pallas(q_codes: jax.Array, bucket_codes: jax.Array, *,
     )(q_codes, bucket_codes)
 
 
-def _gather_kernel(cum_ref, starts_ref, out_ref, *, num_sel: int):
-    """out[q, p] = starts[q, j] + (p - cum[q, j]) with j s.t.
-    cum[q, j] <= p < cum[q, j+1] — the CSR position of probed item p."""
-    cum = cum_ref[...]                                     # (BQ, S+1)
-    starts = starts_ref[...]                               # (BQ, S)
-    bqn, P = out_ref.shape
-    p = jax.lax.broadcasted_iota(jnp.int32, (bqn, P), 1)
+def _gather_kernel(lo_ref, off_ref, out_ref, *, bp: int, n_s: int):
+    """Transposed layout: slots on sublanes, queries on lanes.
 
-    def body(i, base):
-        lo = jax.lax.dynamic_slice_in_dim(cum, i, 1, axis=1)       # (BQ, 1)
-        hi = jax.lax.dynamic_slice_in_dim(cum, i + 1, 1, axis=1)
-        st = jax.lax.dynamic_slice_in_dim(starts, i, 1, axis=1)
-        inb = jnp.logical_and(p >= lo, p < hi)
-        return base + jnp.where(inb, st - lo, 0)
+    out[p, q] = p + off[j, q] for the last run j with lo[j, q] <= p, where
+    off = start - lo. The runs are contiguous and ascending, so that run
+    is the one holding slot p (an empty run shares its lo with the next
+    run, which comes later and wins). The run axis is the minor grid axis:
+    the output block accumulates the overwrite across run blocks.
+    """
+    pi = pl.program_id(1)
+    s = pl.program_id(2)
+    bs = lo_ref.shape[0]
 
-    base = jax.lax.fori_loop(
-        0, num_sel, body, jnp.zeros((bqn, P), jnp.int32))
-    out_ref[...] = base + p
+    @pl.when(s == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    p = pi * bp + jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+    p_lo = pi * bp
+
+    # whole-block shortcuts: a run block that starts after this slot
+    # chunk changes nothing; one that lies wholly at or before its first
+    # slot leaves every slot on the block's last run.
+    @pl.when(jnp.logical_and(jnp.min(lo_ref[...]) < p_lo + bp,
+                             jnp.max(lo_ref[...]) > p_lo))
+    def _walk():
+        def body(t, acc):
+            lo = lo_ref[pl.ds(t, 1), :]                            # (1, BQ)
+            off = off_ref[pl.ds(t, 1), :]
+            return jnp.where(p >= lo, off, acc)
+
+        out_ref[...] = jax.lax.fori_loop(0, bs, body, out_ref[...])
+
+    @pl.when(jnp.max(lo_ref[...]) <= p_lo)
+    def _before():
+        out_ref[...] = jnp.broadcast_to(off_ref[pl.ds(bs - 1, 1), :],
+                                        out_ref.shape)
+
+    @pl.when(s == n_s - 1)
+    def _finish():
+        out_ref[...] = out_ref[...] + p
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("num_probe", "bq", "interpret"))
-def bucket_gather_pallas(cum: jax.Array, starts: jax.Array,
-                         num_probe: int, *, bq: int = 8,
-                         interpret: bool = False) -> jax.Array:
-    """Segmented candidate gather: CSR positions of the first ``num_probe``
-    probed items per query.
+                   static_argnames=("num_slots", "bp", "interpret"))
+def bucket_gather_pallas(lo: jax.Array, off: jax.Array, num_slots: int, *,
+                         bp: int, interpret: bool = False) -> jax.Array:
+    """Segmented candidate gather in the transposed layout.
 
     Args:
-      cum:    (Q, S+1) int32 — exclusive prefix sizes of the per-query
-              probe-ordered selected buckets (cum[:, 0] == 0). The selected
-              buckets must cover >= num_probe items (guaranteed when
-              S = min(B, num_probe): every bucket holds >= 1 item).
-      starts: (Q, S) int32 — CSR start offset of each selected bucket.
+      lo:  (S, Q) int32 — first probed slot of each run (``cum[:, :-1]``
+           transposed); padded runs carry a value past every slot.
+      off: (S, Q) int32 — CSR start minus first slot of each run.
+      num_slots: output rows, a multiple of ``bp``.
 
-    Returns: (Q, num_probe) int32 CSR positions.
+    Returns: (num_slots, Q) int32 — the CSR position of each probed slot.
+    Pre-padded shapes required: S % 8 == 0, Q % 128 == 0 (pad in
+    kernels/ops.py). Runs stream in blocks of up to ``GATHER_BS``.
     """
-    Q, S1 = cum.shape
-    S = S1 - 1
-    if starts.shape != (Q, S) or Q % bq:
+    S, Q = lo.shape
+    bs = min(GATHER_BS, S)
+    if off.shape != (S, Q) or S % bs or Q % LANES or num_slots % bp:
         raise ValueError(
-            f"bucket_gather_pallas precondition: starts {starts.shape} "
-            f"must be (Q={Q}, S={S}) with Q % {bq} == 0 (pad in "
-            f"kernels/ops.py)")
-    grid = (Q // bq,)
+            f"bucket_gather_pallas precondition: lo {lo.shape} == off "
+            f"{off.shape} with S % {bs} == 0, Q % {LANES} == 0 and "
+            f"num_slots={num_slots} % {bp} == 0 (pad in kernels/ops.py)")
+    n_s = S // bs
+    grid = (Q // LANES, num_slots // bp, n_s)    # runs minor: sequential
     return pl.pallas_call(
-        functools.partial(_gather_kernel, num_sel=S),
+        functools.partial(_gather_kernel, bp=bp, n_s=n_s),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq, S + 1), lambda i: (i, 0)),
-            pl.BlockSpec((bq, S), lambda i: (i, 0)),
+            pl.BlockSpec((bs, LANES), lambda i, j, s: (s, i)),
+            pl.BlockSpec((bs, LANES), lambda i, j, s: (s, i)),
         ],
-        out_specs=pl.BlockSpec((bq, num_probe), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Q, num_probe), jnp.int32),
+        out_specs=pl.BlockSpec((bp, LANES), lambda i, j, s: (j, i)),
+        out_shape=jax.ShapeDtypeStruct((num_slots, Q), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(cum, starts)
+    )(lo, off)

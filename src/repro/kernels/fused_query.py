@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.annotations import KernelAnnotation, SentinelSpec
+from repro.kernels.mips_topk import _iter_topk
 
 NEG = -3e38       # sentinel score for padded candidate slots (id -1)
 
@@ -43,16 +44,18 @@ _BP = 128         # candidate chunk width (the wrapper's fixed bp)
 # deliberately revisits the (i, 0) output blocks — the running phase-1
 # top-k' buffer is the TPU output-revisiting accumulate, safe only
 # because the minor grid axis is sequential. Transient peak: the gathered
-# int8 chunk + its f32 dequant + scale/score/position lanes, the
-# concatenated (BQ, K' + BP) merge buffers, and the (BQ, K', d) f32
-# survivor gather of the final rescore.
+# int8 chunk + its f32 dequant + scale/score/position lanes, the run
+# expansion's lane iota and masked column tile over (BQ, S+1), five
+# (BQ, K' + BP) merge tiles, and the (BQ, K', d) f32 survivor gather of
+# the final rescore.
 ANNOTATION = KernelAnnotation(
     name="fused_query",
     grid_names=("queries", "cand_chunks"),
     revisit_dims=(1,),
     extra_vmem=lambda ins, outs: (
         ins[0][0] * _BP * (5 * ins[0][1] + 16)
-        + 2 * ins[0][0] * (outs[0][1] + _BP) * 4
+        + 2 * ins[1][0] * ins[1][1] * 4
+        + 5 * ins[0][0] * (outs[0][1] + _BP) * 4
         + ins[0][0] * outs[0][1] * (4 * ins[0][1] + 8)),
     sentinel=SentinelSpec(
         kind="vals", value=NEG,
@@ -63,24 +66,6 @@ ANNOTATION = KernelAnnotation(
          "kernel serves shards up to N*d*(1+4+4/d) bytes of half the VMEM "
          "budget; shard (distributed engine) beyond that",
 )
-
-
-def _iter_topk(scores: jax.Array, ids: jax.Array, k: int):
-    """K rounds of argmax+mask over the last axis. scores (BQ, M).
-
-    Ties pick the lowest column — with columns in canonical CSR order
-    this reproduces lax.top_k's first-occurrence tie policy.
-    """
-    vals_out = []
-    ids_out = []
-    s = scores
-    for _ in range(k):
-        pos = jnp.argmax(s, axis=-1)                      # (BQ,)
-        row = jnp.arange(s.shape[0])
-        vals_out.append(s[row, pos])
-        ids_out.append(ids[row, pos])
-        s = s.at[row, pos].set(NEG)
-    return jnp.stack(vals_out, axis=-1), jnp.stack(ids_out, axis=-1)
 
 
 def _expand_chunk(cum: jax.Array, starts: jax.Array, base: jax.Array,
@@ -95,17 +80,22 @@ def _expand_chunk(cum: jax.Array, starts: jax.Array, base: jax.Array,
     and the static planned width (masks chunk-grid padding).
     """
     S = starts.shape[1]
-    off = jnp.zeros(base.shape, jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, cum.shape, 1)
+
+    def column(x, i):
+        # run i's value per query, by a masked row sum: the TPU kernel
+        # compiler has no lane-axis dynamic slice
+        return jnp.sum(jnp.where(lane[:, :x.shape[1]] == i, x, 0), axis=1,
+                       keepdims=True)
 
     def body(i, off):
-        lo = jax.lax.dynamic_slice_in_dim(cum, i, 1, axis=1)
-        hi = jax.lax.dynamic_slice_in_dim(cum, i + 1, 1, axis=1)
-        st = jax.lax.dynamic_slice_in_dim(starts, i, 1, axis=1)
-        inb = (base >= lo) & (base < hi)
-        return off + jnp.where(inb, st - lo, 0)
+        # the last run starting at or before a slot holds it (runs are
+        # contiguous and ascending; an empty run yields to the next one)
+        lo = column(cum, i)
+        return jnp.where(base >= lo, column(starts, i) - lo, off)
 
-    off = jax.lax.fori_loop(0, S, body, off)
-    tot = jax.lax.dynamic_slice_in_dim(cum, S, 1, axis=1)
+    off = jax.lax.fori_loop(0, S, body, jnp.zeros(base.shape, jnp.int32))
+    tot = cum[:, S:S + 1]
     valid = (base < tot) & (base < total)
     return base + off, valid
 

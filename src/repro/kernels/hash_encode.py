@@ -38,8 +38,8 @@ WORD = 32
 # kernelcheck model claims (DESIGN.md §16): the K-slab grid dimension
 # deliberately revisits the (i, j) output block (the f32 accumulator lives
 # in scratch across the K loop — sequential-grid accumulate, NOT safe under
-# "arbitrary" grid semantics); the transient peak is the (BN, BL) sign-bit
-# tile plus the shifted word tile on the final K step. Row/column padding
+# "arbitrary" grid semantics); the transient peak is on the final K step:
+# the (BN, BL) projection plus its power-of-two bit terms. Row/column padding
 # is sliced off by the wrapper; in-word bit padding is masked to 0 in the
 # last uint32 word (sign(0) = 1 would otherwise pollute Hamming distances).
 ANNOTATION = KernelAnnotation(
@@ -67,16 +67,23 @@ def _encode_kernel(x_ref, a_ref, tail_ref, atail_ref, out_ref, acc_ref, *,
         x_ref[...].astype(jnp.float32),
         a_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
     @pl.when(k == n_k - 1)
     def _finish():
         proj = acc_ref[...] + tail_ref[...] * atail_ref[...]
-        bits = (proj >= 0.0).astype(jnp.uint32)            # (BN, BL)
-        bn, bl = bits.shape
-        words = bits.reshape(bn, bl // WORD, WORD)
-        shifts = jnp.arange(WORD, dtype=jnp.uint32)[None, None, :]
-        out_ref[...] = jnp.sum(words << shifts, axis=-1).astype(jnp.uint32)
+        bl = proj.shape[1]
+        # bit b of word w sits in lane 32w + b. Words are summed in int32
+        # (the TPU kernel compiler has no unsigned reduction); the terms
+        # are distinct powers of two, so no sum carries and the int32
+        # pattern is the packed word.
+        shift = jax.lax.broadcasted_iota(jnp.int32, (1, bl), 1) % WORD
+        terms = jnp.where(proj >= 0.0, jnp.left_shift(1, shift), 0)
+        for w in range(bl // WORD):
+            out_ref[:, w:w + 1] = jax.lax.bitcast_convert_type(
+                jnp.sum(terms[:, w * WORD:(w + 1) * WORD], axis=1,
+                        keepdims=True), jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bl", "bd", "interpret"))

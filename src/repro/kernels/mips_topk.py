@@ -32,17 +32,18 @@ NEG = -3.0e38
 # deliberately revisits the (i, 0) output blocks — the running top-k
 # buffer is the canonical TPU output-revisiting accumulate, safe only
 # because the minor grid axis is sequential. Transient peak: the (BQ, BN)
-# score tile plus the concatenated (BQ, K + BN) merge buffers (vals f32 +
-# ids i32). Padded item rows are the PR 4 shard-padding-leak surface: the
-# wrapper's sentinel feature column makes them score real_dot - 1e30 so
-# they rank strictly last even against negative real scores.
+# score tile plus five (BQ, K + BN) merge tiles (vals f32, ids i32, the
+# column iota, the masked scores and one select). Padded item rows are
+# the shard-padding-leak surface: the wrapper's sentinel feature column
+# makes them score real_dot - 1e30 so they rank strictly last even
+# against negative real scores.
 ANNOTATION = KernelAnnotation(
     name="mips_topk",
     grid_names=("queries", "items"),
     revisit_dims=(1,),
     extra_vmem=lambda ins, outs: (
         ins[0][0] * ins[1][0] * 4
-        + 2 * ins[0][0] * (ins[1][0] + outs[0][1]) * 4),
+        + 5 * ins[0][0] * (ins[1][0] + outs[0][1]) * 4),
     sentinel=SentinelSpec(
         kind="vals", value=-1e30,
         note="padded item rows score real_dot - 1e30 via the appended "
@@ -52,17 +53,28 @@ ANNOTATION = KernelAnnotation(
 
 
 def _iter_topk(scores: jax.Array, ids: jax.Array, k: int):
-    """K rounds of argmax+mask over the last axis. scores (BQ, M)."""
-    vals_out = []
-    ids_out = []
+    """K rounds of (row max, mask) over the last axis. scores (BQ, M).
+
+    Ties pick the lowest column (argmax's first occurrence). Built from
+    row reductions and iota masks only: the TPU kernel compiler has no
+    lowering for a per-row gather or scatter.
+    """
+    bq, m = scores.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (bq, m), 1)
+    out_col = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
+    vals = jnp.zeros((bq, k), scores.dtype)
+    out_ids = jnp.zeros((bq, k), ids.dtype)
     s = scores
-    for _ in range(k):
-        pos = jnp.argmax(s, axis=-1)                      # (BQ,)
-        row = jnp.arange(s.shape[0])
-        vals_out.append(s[row, pos])
-        ids_out.append(ids[row, pos])
-        s = s.at[row, pos].set(NEG)
-    return jnp.stack(vals_out, axis=-1), jnp.stack(ids_out, axis=-1)
+    for r in range(k):
+        top = jnp.max(s, axis=-1, keepdims=True)                  # (BQ, 1)
+        pos = jnp.min(jnp.where(s == top, col, m), axis=-1,
+                      keepdims=True)
+        hit = col == pos
+        sel = jnp.sum(jnp.where(hit, ids, 0), axis=-1, keepdims=True)
+        vals = jnp.where(out_col == r, top, vals)
+        out_ids = jnp.where(out_col == r, sel, out_ids)
+        s = jnp.where(hit, NEG, s)
+    return vals, out_ids
 
 
 def _topk_kernel(q_ref, it_ref, vals_ref, ids_ref, *, k: int, bn: int,
@@ -78,9 +90,10 @@ def _topk_kernel(q_ref, it_ref, vals_ref, ids_ref, *, k: int, bn: int,
     it = it_ref[...].astype(jnp.float32)                  # (BN, d)
     scores = jax.lax.dot_general(
         q, it, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)               # (BQ, BN)
-    blk_ids = (nb * bn + jnp.arange(bn, dtype=jnp.int32))[None, :]
-    blk_ids = jnp.broadcast_to(blk_ids, scores.shape)
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)              # (BQ, BN)
+    blk_ids = nb * bn + jax.lax.broadcasted_iota(
+        jnp.int32, scores.shape, 1)
 
     all_vals = jnp.concatenate([vals_ref[...], scores], axis=-1)
     all_ids = jnp.concatenate([ids_ref[...], blk_ids], axis=-1)

@@ -90,6 +90,10 @@ def _require_nonempty(op: str, **dims: int) -> None:
             f"every listed dimension must be >= 1")
 
 
+def _round_up(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
+
+
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
     n = x.shape[axis]
     pad = (-n) % mult
@@ -248,21 +252,28 @@ def bucket_gather(cum: jax.Array, starts: jax.Array, num_probe: int, *,
             cum.shape[0], num_probe)
     if impl == "ref":
         return _ref.bucket_gather_ref(cum, starts, num_probe)
-    bq = 8
-    Q = cum.shape[0]
-    # row padding: a single covering run [0, num_probe) keeps padded rows
-    # in-contract (runs must cover the probe budget).
-    pad = (-Q) % bq
+    Q, S = starts.shape
+    # slots on sublanes, queries on lanes: the kernel reads one run's row
+    # across 128 queries with a dynamic sublane index
+    lanes = _bucket_probe_mod.LANES
+    bp = min(_bucket_probe_mod.GATHER_BP, _round_up(num_probe, 8))
+    num_slots = _round_up(num_probe, bp)
+    bs = min(_bucket_probe_mod.GATHER_BS, _round_up(S, 8))
+    lo = cum[:, :-1]
+    off = starts - lo
+    # padded runs start past every slot, so they never win a slot; padded
+    # query rows get a single covering run [0, num_slots) and are sliced
+    # off (rows must stay in-contract: runs cover the probe budget)
+    lo = _pad_to(lo, 1, bs, value=num_slots)
+    off = _pad_to(off, 1, bs)
+    pad = (-Q) % lanes
     if pad:
-        pcum = jnp.concatenate(
-            [jnp.zeros((pad, 1), cum.dtype),
-             jnp.full((pad, cum.shape[1] - 1), num_probe, cum.dtype)], axis=1)
-        cum = jnp.concatenate([cum, pcum], axis=0)
-        starts = jnp.concatenate(
-            [starts, jnp.zeros((pad, starts.shape[1]), starts.dtype)], axis=0)
-    out = bucket_gather_pallas(cum, starts, num_probe, bq=bq,
+        cover = jnp.full((pad, lo.shape[1]), num_slots, lo.dtype)
+        lo = jnp.concatenate([lo, cover.at[:, 0].set(0)], axis=0)
+        off = _pad_to(off, 0, lanes)
+    out = bucket_gather_pallas(lo.T, off.T, num_slots, bp=bp,
                                interpret=not _on_tpu())
-    return out[:Q]
+    return out[:num_probe, :Q].T
 
 
 def fused_query(queries: jax.Array, cum: jax.Array, starts: jax.Array,
@@ -441,7 +452,7 @@ def _probe_delta_scan() -> List[str]:
 
 
 def _probe_bucket_gather() -> List[str]:
-    q, s, p = 3, 4, 7                        # q pads 3 -> 8 covering runs
+    q, s, p = 3, 4, 7                        # q pads 3 -> 128 query lanes
     sizes = jnp.full((q, s), 2, jnp.int32)   # 4 runs x 2 items >= p
     cum = jnp.concatenate(
         [jnp.zeros((q, 1), jnp.int32), jnp.cumsum(sizes, axis=1)], axis=1)
@@ -450,10 +461,10 @@ def _probe_bucket_gather() -> List[str]:
         "bucket_gather",
         bucket_gather(cum, starts, p, impl="pallas"),
         _ref.bucket_gather_ref(cum, starts, p))
-    # row-padding audit (PR 10): the wrapper's covering run [0, num_probe)
-    # keeps the 5 padded query rows in-contract; CSR position 0 is a REAL
-    # position, so any padded row leaking into the sliced result would
-    # alias item 0 — assert the slice boundary, not just parity.
+    # row-padding audit: the wrapper's covering run keeps the 125 padded
+    # query lanes in-contract; CSR position 0 is a REAL position, so any
+    # padded lane leaking into the sliced result would alias item 0 —
+    # assert the slice boundary, not just parity.
     got = bucket_gather(cum, starts, p, impl="pallas")
     if got.shape != (q, p):
         problems.append(

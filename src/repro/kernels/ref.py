@@ -28,7 +28,8 @@ def hash_encode_ref(x: jax.Array, A: jax.Array,
     the SIMPLE-LSH augmentation ``tail * a_tail`` is added to the projection
     (eq. 8 folded, DESIGN.md §3). Returns packed (N, ceil(L/32)) uint32.
     """
-    proj = x.astype(jnp.float32) @ A.astype(jnp.float32)
+    proj = jnp.matmul(x.astype(jnp.float32), A.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
     if tail is not None:
         proj = proj + tail.astype(jnp.float32)[:, None] * a_tail[None, :]
     return pack_bits((proj >= 0.0).astype(jnp.uint8))
@@ -43,7 +44,9 @@ def hamming_ref(q_codes: jax.Array, db_codes: jax.Array) -> jax.Array:
 def mips_topk_ref(queries: jax.Array, items: jax.Array, k: int
                   ) -> Tuple[jax.Array, jax.Array]:
     """Oracle for the streaming top-k kernel: exact matmul + lax.top_k."""
-    scores = queries.astype(jnp.float32) @ items.astype(jnp.float32).T
+    scores = jnp.matmul(queries.astype(jnp.float32),
+                        items.astype(jnp.float32).T,
+                        precision=jax.lax.Precision.HIGHEST)
     vals, ids = jax.lax.top_k(scores, k)
     return vals, ids.astype(jnp.int32)
 

@@ -42,7 +42,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import (ARCH_IDS, SHAPES, ModelConfig, get_config,
                                 shape_cells)
 from repro.data.tokens import decode_batch_specs, train_batch_specs
-from repro import compat
 from repro.launch.mesh import ambient_mesh, make_production_mesh
 from repro.models import lm
 from repro.parallel import analytic
@@ -247,7 +246,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
             compiled = lowered.compile()
             t2 = time.time()
 
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis() or {}
         try:
             mem = compiled.memory_analysis()
             mem_d = {
@@ -392,7 +391,7 @@ def run_mips_cell(mesh_kind: str, out_dir: str = OUT_DIR) -> Dict[str, Any]:
         t1 = time.time()
         compiled = lowered.compile()
         t2 = time.time()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis() or {}
         text = compiled.as_text()
         colls = hlo.parse_collectives(text, chips)
         csum = hlo.summarize_collectives(colls)

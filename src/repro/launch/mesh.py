@@ -10,17 +10,10 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed in jax 0.5.x; older releases default to Auto anyway.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -30,11 +23,8 @@ make_compat_mesh = _mk  # public alias for tests/examples
 
 def ambient_mesh(mesh: Mesh):
     """Context manager installing ``mesh`` as the ambient mesh for
-    ``with_sharding_constraint`` during tracing. ``jax.set_mesh`` where
-    available; on older jax the Mesh object itself is the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    ``with_sharding_constraint`` during tracing."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
 from repro.core import hashing
 from repro.core.family import SimpleLSHFamily
 from repro.core.index import index_bits
@@ -261,7 +259,7 @@ def sharded_lsh_topk_tokens(index: VocabIndex, hidden: jax.Array,
         bv, bp = jax.lax.top_k(fv, k)
         return bv, jnp.take_along_axis(fi, bp, axis=1)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(), P(None, None), P(),
                   P(None, axis)),

@@ -17,7 +17,7 @@ Why analytic instead of asking XLA: the hot path is a relay of separate
 host-orchestrated dispatches (no single compiled program to interrogate),
 and XLA:CPU's ``cost_analysis`` is unreliable on scanned/whiled bodies
 (see parallel/analytic.py). :func:`xla_cost` still exposes the compiled
-estimate through ``repro.compat.cost_analysis`` for cross-checking a
+estimate through ``Compiled.cost_analysis`` for cross-checking a
 single jitted stage — the unit tests pin the analytic hash_encode flops
 against it.
 
@@ -154,16 +154,14 @@ def query_stage_costs(shape: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
 
 def xla_cost(fn: Callable, *args, **kwargs) -> Optional[Dict[str, float]]:
     """XLA's own compiled-cost estimate for one jittable callable:
-    ``{"flops", "hbm_bytes"}`` via ``repro.compat.cost_analysis``, or
+    ``{"flops", "hbm_bytes"}`` via ``Compiled.cost_analysis``, or
     None when the backend reports nothing. The cross-check arm for the
     analytic model (unit-tested on hash_encode); NOT used on the hot
     path — lowering + compiling per query would dwarf the query."""
     import jax
 
-    from repro import compat
-
     compiled = jax.jit(fn).lower(*args, **kwargs).compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     if not cost:
         return None
     out = {"flops": float(cost.get("flops", 0.0))}

@@ -35,6 +35,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.topk import EXACT
 from repro.kernels import ops
 
 RANK_SENTINEL = jnp.iinfo(jnp.int32).max
@@ -134,7 +135,7 @@ def merged_rerank(store_items: jax.Array, delta_items: jax.Array,
     vecs = jnp.where(in_base[..., None], store_items[base_pos],
                      delta_items[slot])
     live = jnp.where(in_base, store_live[base_pos], delta_live[slot])
-    scores = jnp.einsum("qd,qpd->qp", queries, vecs)
+    scores = jnp.einsum("qd,qpd->qp", queries, vecs, precision=EXACT)
     scores = jnp.where(live, scores, -jnp.inf)
     vals, pos = jax.lax.top_k(scores, k)
     return vals, jnp.take_along_axis(cand, pos, axis=1)

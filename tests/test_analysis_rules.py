@@ -66,7 +66,6 @@ def test_r2_flags_partial_shard_map_alias(tmp_path):
     # the PR 4 collective idiom: partial alias -> shard_map -> jax.jit
     found = _lint_src(tmp_path, """
         import functools, jax
-        from repro import compat
 
         def _shard_query(x, *, k):
             resolve_tracker(None)
@@ -74,7 +73,8 @@ def test_r2_flags_partial_shard_map_alias(tmp_path):
 
         def build(mesh):
             body = functools.partial(_shard_query, k=5)
-            return jax.jit(compat.shard_map(body, mesh, (), ()))
+            return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(),
+                                         out_specs=()))
     """)
     assert _rules_of(found) == ["R2"]
     assert "_shard_query" in found[0].message

@@ -243,6 +243,20 @@ def test_quantized_requires_fused_engine():
         QueryEngine(idx, engine="bucket", quantized=True)
 
 
+@pytest.mark.parametrize("impl", ["auto", "pallas"])
+def test_fused_engine_refuses_tpu_at_construction(monkeypatch, impl):
+    """On a TPU the fused kernel does not lower: construction says so,
+    instead of a compiler error surfacing mid-query. The jnp reference
+    arm and the other engines still construct."""
+    items = _longtail_items(64, 8, jax.random.PRNGKey(27))
+    idx = _build(items, "simple")
+    monkeypatch.setattr(engine_mod.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="does not compile"):
+        QueryEngine(idx, engine="fused", impl=impl)
+    assert QueryEngine(idx, engine="fused", impl="ref").engine == "fused"
+    assert QueryEngine(idx, engine="bucket", impl=impl).engine == "bucket"
+
+
 def test_quantize_payload_roundtrip():
     items = _longtail_items(50, 8, jax.random.PRNGKey(27))
     payload, scale = quantize_payload(items)

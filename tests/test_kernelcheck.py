@@ -360,15 +360,27 @@ def test_regress_bound_trips_on_dirty_report(tmp_path):
     assert any(r["status"] == "violated" for r in rows)
 
 
+# what the code decides per kernel shape class. The jaxpr-derived counts
+# ("jaxpr", "ratio") follow the installed jax's tracing and are not pinned.
+PINNED_FIELDS = ("shapes", "grid", "kernel", "vmem_bytes", "vmem_frac",
+                 "declared")
+
+
+def _pinned(kernels):
+    return {op: [{f: c[f] for f in PINNED_FIELDS} for c in v["classes"]]
+            for op, v in kernels.items()}
+
+
 def test_committed_trajectory_report_matches_current():
-    """BENCH_0008.json (the committed kernelcheck trajectory entry) must
-    stay in sync with what the analyzer derives from the code."""
-    path = km.REPO_ROOT / "BENCH_0008.json"
-    committed = json.loads(path.read_text())
-    assert committed["bench"] == "kernelcheck"
+    """The newest committed kernelcheck report (BENCH_*.json) must stay in
+    sync with what the code decides: grids, VMEM bytes and declared
+    bills."""
+    reports = [p for p in sorted(km.REPO_ROOT.glob("BENCH_*.json"))
+               if json.loads(p.read_text()).get("bench") == "kernelcheck"]
+    committed = json.loads(reports[-1].read_text())
     _, current = kc.run_kernelcheck(probes=False)
-    assert committed["kernels"] == json.loads(
-        json.dumps(current["kernels"]))
+    assert _pinned(committed["kernels"]) == _pinned(
+        json.loads(json.dumps(current["kernels"])))
     assert committed["clean"] == 1
 
 

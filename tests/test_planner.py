@@ -65,6 +65,45 @@ def test_plan_global_picks_smallest_grid_point():
     assert planner.plan_global(hand_table(), 0.2).num_probe == 10
 
 
+def _within_range_positions_loop(order_ids, range_id, truth_ids, m):
+    """Reference for calibrate_from_order: each truth item's position
+    within its range's probe order, by one cumulative count per range."""
+    q, n = order_ids.shape
+    rows = np.arange(q)[:, None]
+    sorted_rid = range_id[order_ids]
+    wpos_sorted = np.empty((q, n), np.int64)
+    for j in range(m):
+        mask = sorted_rid == j
+        wpos_sorted[mask] = (np.cumsum(mask, axis=1) - 1)[mask]
+    wpos = np.empty((q, n), np.int64)
+    wpos[rows, order_ids] = wpos_sorted
+    return np.take_along_axis(wpos, truth_ids, axis=1).reshape(-1)
+
+
+@pytest.mark.parametrize("q,n,m", [(3, 7, 1), (5, 50, 3), (17, 1000, 8),
+                                   (8, 4000, 32)])
+def test_calibrate_from_order_matches_per_range_loop(q, n, m):
+    rng = np.random.default_rng(q * n + m)
+    order = np.stack([rng.permutation(n) for _ in range(q)])
+    rid = rng.integers(0, m, n)
+    truth = np.stack([rng.choice(n, min(10, n), replace=False)
+                      for _ in range(q)])
+    grid = np.arange(n + 1)
+    table = planner.calibrate_from_order(order, rid, truth, num_ranges=m,
+                                         grid=grid)
+    wpos = _within_range_positions_loop(order, rid, truth, m)
+    counts = np.bincount(rid, minlength=m)
+    t_rid = rid[truth.reshape(-1)]
+    want = np.zeros((m, grid.size), np.float32)
+    for j in range(m):
+        sel = t_rid == j
+        eff = np.minimum(grid, counts[j])
+        if sel.any():
+            want[j] = (wpos[sel][None, :] < eff[:, None]).mean(axis=1)
+        want[j, eff >= counts[j]] = 1.0
+    np.testing.assert_array_equal(table.recall_range, want)
+
+
 def test_target_validation():
     for bad in (0.0, -0.5, 1.5):
         with pytest.raises(ValueError, match="recall_target"):
