@@ -220,8 +220,6 @@ def _extract_loadgen(b: dict) -> tuple:
                "every request class must meet its recall contract"),
         _bound("trace_valid", bool(a.get("trace_valid")),
                "exported Chrome trace must pass schema validation"),
-        _bound("cost_attrs_present", bool(a.get("cost_attrs_present")),
-               "hot-path trace slices must carry flops/hbm_bytes attrs"),
     ]
     return shape, metrics, bounds
 

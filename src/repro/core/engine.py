@@ -42,7 +42,6 @@ from repro.core import hashing
 from repro.core.bucket_index import BucketIndex, build_bucket_index
 from repro.core.topk import rerank
 from repro.kernels import ops
-from repro.obs import cost
 from repro.obs.trace import span_or_null
 from repro.obs.tracker import resolve_tracker
 
@@ -90,10 +89,7 @@ def _directory_order(buckets: BucketIndex, q_codes: jax.Array,
     """(Q, B) probe-ordered bucket indices: directory match -> per-bucket
     rank -> stable argsort (ties break by CSR bucket position). The shared
     front half of every bucket-store traversal (staged, planned, fused)."""
-    Q = q_codes.shape[0]
     with span_or_null(tracker, "repro.engine.directory_match") as sp:
-        sp.set_attrs(**cost.directory_match_cost(
-            Q, buckets.num_buckets, buckets.hash_bits))
         matches = match_fn(q_codes, buckets.bucket_code)         # (Q, B)
         bucket_rank = buckets.rank[buckets.bucket_rid[None, :], matches]
         return sp.sync(
@@ -116,13 +112,16 @@ def _probe_runs(buckets: BucketIndex, order: jax.Array,
 
 
 def _planned_runs(buckets: BucketIndex, order: jax.Array,
-                  budgets: Sequence[int]) -> Tuple[jax.Array, jax.Array]:
+                  budgets: Sequence[int], tracker=None
+                  ) -> Tuple[jax.Array, jax.Array]:
     """(cum (Q, B+1), starts (Q, B)) CSR runs realizing per-range budgets:
     each probe-ordered bucket takes what is left of its range's budget
     (zero-take buckets contribute empty runs)."""
     sizes_o = (buckets.bucket_start[1:] - buckets.bucket_start[:-1])[order]
     starts = buckets.bucket_start[:-1][order]
-    take = planned_take(buckets.bucket_rid[order], sizes_o, budgets)
+    rid_o = buckets.bucket_rid[order]
+    with span_or_null(tracker, "repro.engine.planned_take") as sp:
+        take = sp.sync(planned_take(rid_o, sizes_o, budgets))
     cum = jnp.concatenate(
         [jnp.zeros((order.shape[0], 1), jnp.int32),
          jnp.cumsum(take, axis=-1, dtype=jnp.int32)], axis=-1)
@@ -149,10 +148,8 @@ def bucket_candidates(buckets: BucketIndex, q_codes: jax.Array,
                          f"(0, N={buckets.num_items}]")
     if match_fn is None:
         match_fn = _default_match(buckets, impl)
-    Q = q_codes.shape[0]
     order = _directory_order(buckets, q_codes, match_fn, tracker)
     with span_or_null(tracker, "repro.engine.segmented_gather") as sp:
-        sp.set_attrs(**cost.segmented_gather_cost(Q, num_probe))
         cum, starts = _probe_runs(buckets, order, num_probe)
         csr_pos = ops.bucket_gather(cum, starts, num_probe, impl=impl)
         return sp.sync(buckets.item_ids[csr_pos])
@@ -236,14 +233,12 @@ def planned_bucket_candidates(buckets: BucketIndex, q_codes: jax.Array,
     budgets, total = check_budgets(budgets, range_counts)
     if match_fn is None:
         match_fn = _default_match(buckets, impl)
-    Q = q_codes.shape[0]
     order = _directory_order(buckets, q_codes, match_fn, tracker)
     with span_or_null(tracker, "repro.engine.segmented_gather") as sp:
-        sp.set_attrs(**cost.segmented_gather_cost(Q, total))
         # every query's takes sum to exactly ``total`` (each range always
         # contributes its full effective budget), so no covering run is
         # needed
-        cum, starts = _planned_runs(buckets, order, budgets)
+        cum, starts = _planned_runs(buckets, order, budgets, tracker)
         csr_pos = ops.bucket_gather(cum, starts, total, impl=impl)
         return sp.sync(buckets.item_ids[csr_pos])
 
@@ -280,11 +275,8 @@ def fused_bucket_query(buckets: BucketIndex, q_codes: jax.Array,
                              f"(0, N={buckets.num_items}]")
     order = _directory_order(buckets, q_codes, match_fn, tracker)
     with span_or_null(tracker, "repro.engine.fused_query") as sp:
-        sp.set_attrs(**cost.fused_query_cost(
-            q_codes.shape[0], total, queries.shape[1], int(k),
-            max(int(k), min(max(4 * int(k), 32), total))))
         if budgets is not None:
-            cum, starts = _planned_runs(buckets, order, budgets)
+            cum, starts = _planned_runs(buckets, order, budgets, tracker)
         else:
             cum, starts = _probe_runs(buckets, order, total)
         vals, pos = ops.fused_query(queries, cum, starts, items_csr,
@@ -322,21 +314,21 @@ def planned_dense_candidates(buckets: BucketIndex, q_codes: jax.Array,
     budgets, total = check_budgets(budgets, range_counts)
     if match_fn is None:
         match_fn = _default_match(buckets, impl)
-    Q = q_codes.shape[0]
     with span_or_null(tracker, "repro.engine.dense_match") as sp:
-        sp.set_attrs(**cost.dense_match_cost(
-            Q, buckets.num_items, buckets.hash_bits))
         matches = match_fn(q_codes, db_codes)                    # (Q, N)
         item_rank = buckets.rank[range_id[None, :], matches]
         rank_csr = item_rank[:, buckets.item_ids]
         order = sp.sync(
             jnp.argsort(rank_csr, axis=-1, stable=True))         # (Q, N)
     with span_or_null(tracker, "repro.engine.dense_select") as sp:
-        sp.set_attrs(**cost.dense_select_cost(Q, buckets.num_items))
         rid_o = range_id[buckets.item_ids][order]
-        # unit sizes make range_cum_before the within-range probe position
-        wpos = range_cum_before(rid_o, jnp.ones_like(rid_o), len(budgets))
-        keep = wpos < jnp.asarray(budgets, jnp.int32)[rid_o]
+        with span_or_null(tracker, "repro.engine.planned_take") as sp_take:
+            # unit sizes make range_cum_before the within-range probe
+            # position
+            wpos = range_cum_before(rid_o, jnp.ones_like(rid_o),
+                                    len(budgets))
+            keep = sp_take.sync(
+                wpos < jnp.asarray(budgets, jnp.int32)[rid_o])
         # exactly ``total`` kept per query; stable sort pulls them to the
         # front in canonical order
         sel = jnp.argsort(~keep, axis=-1, stable=True)[:, :total]
@@ -357,17 +349,13 @@ def dense_candidates(buckets: BucketIndex, q_codes: jax.Array,
     num_probe = int(num_probe)
     if match_fn is None:
         match_fn = _default_match(buckets, impl)
-    Q = q_codes.shape[0]
     with span_or_null(tracker, "repro.engine.dense_match") as sp:
-        sp.set_attrs(**cost.dense_match_cost(
-            Q, buckets.num_items, buckets.hash_bits))
         matches = match_fn(q_codes, db_codes)                    # (Q, N)
         item_rank = buckets.rank[range_id[None, :], matches]
         # reorder columns to CSR so the stable argsort ties on CSR position
         rank_csr = item_rank[:, buckets.item_ids]
         order = sp.sync(jnp.argsort(rank_csr, axis=-1, stable=True))
     with span_or_null(tracker, "repro.engine.dense_select") as sp:
-        sp.set_attrs(**cost.dense_select_cost(Q, buckets.num_items))
         return sp.sync(buckets.item_ids[order[:, :num_probe]])
 
 
@@ -516,9 +504,6 @@ class QueryEngine:
             raise ValueError("pass exactly one of num_probe/budgets")
         tr = self.tracker
         with span_or_null(tr, "repro.engine.hash_encode") as sp:
-            sp.set_attrs(**cost.hash_encode_cost(
-                queries.shape[0], queries.shape[1],
-                getattr(self.index, "code_len", self.buckets.hash_bits)))
             q_codes = sp.sync(
                 encode_queries(self.index, queries, impl=self.impl))
         if budgets is not None:
@@ -556,25 +541,22 @@ class QueryEngine:
         ``budgets`` (per-range budgets) or ``recall_target`` (resolved to
         budgets through the index's calibration table — the recall
         contract) selects the probe set."""
-        if recall_target is not None:
-            if num_probe is not None or budgets is not None:
-                raise ValueError(
-                    "pass one of num_probe/budgets/recall_target")
-            from repro.core.planner import resolve_budgets
-            budgets = resolve_budgets(
-                getattr(self.index, "calib", None), recall_target,
-                k=k).budgets
+        if recall_target is not None and (num_probe is not None
+                                          or budgets is not None):
+            raise ValueError("pass one of num_probe/budgets/recall_target")
         tr = self.tracker
         with span_or_null(tr, "repro.engine.query"):
+            if recall_target is not None:
+                from repro.core.planner import resolve_budgets
+                with span_or_null(tr, "repro.engine.plan"):
+                    budgets = resolve_budgets(
+                        getattr(self.index, "calib", None), recall_target,
+                        k=k).budgets
             if self.engine == "fused":
                 if (num_probe is None) == (budgets is None):
                     raise ValueError("pass exactly one of "
                                      "num_probe/budgets")
                 with span_or_null(tr, "repro.engine.hash_encode") as sp:
-                    sp.set_attrs(**cost.hash_encode_cost(
-                        queries.shape[0], queries.shape[1],
-                        getattr(self.index, "code_len",
-                                self.buckets.hash_bits)))
                     q_codes = sp.sync(encode_queries(
                         self.index, queries, impl=self.impl))
                 items_csr, payload, scale = self._fused_arrays

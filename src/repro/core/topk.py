@@ -7,7 +7,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.obs import cost
 from repro.obs.trace import span_or_null
 
 # f32 products and sums throughout: the TPU's default matmul precision
@@ -35,14 +34,12 @@ def rerank(queries: jax.Array, items: jax.Array, cand_ids: jax.Array, k: int,
     sync points — only pass one from eager callers, never from inside
     jitted code).
     """
-    Q, P = cand_ids.shape
+    Q = cand_ids.shape[0]
     with span_or_null(tracker, "repro.engine.re_rank") as sp:
-        sp.set_attrs(**cost.re_rank_cost(Q, P, queries.shape[1]))
         cand = items[cand_ids]                              # (Q, P, d)
         scores = sp.sync(jnp.einsum("qd,qpd->qp", queries, cand,
                                     precision=EXACT))
     with span_or_null(tracker, "repro.engine.top_k") as sp:
-        sp.set_attrs(**cost.top_k_cost(Q, P, k))
         # first-occurrence duplicate mask without the (Q, P, P) blowup:
         # stable-sort ids per row, flag equal neighbors, scatter back.
         # Unique rows (every engine path) are left bit-identical.

@@ -8,6 +8,14 @@ per-shard -> fleet rollups. Everything is host-side python recorded
 after explicit device-sync boundaries, so attaching a tracker never
 changes traced programs or query results.
 
+Every span is also a ``jax.profiler.TraceAnnotation`` of its name, with
+or without a tracker (without one it is nothing else: no clock, no
+sync). Under ``jax.profiler.trace`` the stages therefore appear on the
+host timeline beside the device ops, on one clock, with the program
+launches each stage issued nested inside it. ``jax.named_scope`` cannot
+name these stages on the device: the query path runs eagerly, and an
+eager primitive is one cached program shared by every stage calling it.
+
 Typical wiring::
 
     from repro import obs

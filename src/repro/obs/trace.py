@@ -1,13 +1,27 @@
 """Span-based tracing of the query hot path (DESIGN.md §13).
 
-A span times one stage — ``hash_encode``, ``directory_match``,
-``segmented_gather``, ``re_rank``, ``top_k`` — with an *explicit
-device-sync boundary*: jax dispatch is asynchronous, so a wall-clock
-reading after an un-synced call measures dispatch latency, not the stage.
-Registering a sync value (``span(name, sync=x)`` or ``sp.sync(x)`` in the
-body) makes the span ``jax.block_until_ready`` it before reading the
-clock. Instrumentation never goes *inside* jitted code and never touches
-values — enabling tracing cannot change query results (parity-tested).
+A span names one stage — ``hash_encode``, ``plan``, ``directory_match``,
+``segmented_gather``, ``planned_take``, ``re_rank``, ``top_k`` — and is
+always a ``jax.profiler.TraceAnnotation`` of that name around its body,
+whether or not a tracker is attached. Under ``jax.profiler.trace`` the
+stage therefore shows on the host timeline of the device trace, on the
+device ops' clock, with the program launches it issued nested inside it;
+that is what charges device time to a stage. ``jax.named_scope`` cannot:
+the query path runs eagerly, and an eager primitive is compiled once per
+shape and shared by every stage that calls it, so a scope never reaches
+its device program. Outside a profiler trace an annotation costs about a
+microsecond.
+
+With no tracker (``span_or_null(None, name)``) the annotation is all a
+span does: no clock, no sync, no record; ``sync`` is the identity and
+``set_attrs`` a no-op. With a tracker a span also times the stage with an
+*explicit device-sync boundary*: jax dispatch is asynchronous, so a
+wall-clock reading after an un-synced call measures dispatch latency, not
+the stage. Registering a sync value (``span(name, sync=x)`` or
+``sp.sync(x)`` in the body) makes the span ``jax.block_until_ready`` it,
+inside the annotation, before reading the clock. Instrumentation never
+goes *inside* jitted code and never touches values — enabling tracing
+cannot change query results (parity-tested).
 
 Spans nest: the tracer keeps a stack and emits each span with its full
 ``path`` (``/``-joined ancestry), so the per-stage breakdown of a
@@ -19,22 +33,24 @@ p50/p90/p99 stage timings for free (``benchmarks/roofline_report.py
 Span records carry ``t0`` (start, seconds since tracker start) alongside
 ``dur_s``, so ``repro.obs.export`` can rebuild exact begin/end pairs for
 Chrome ``trace_event`` output, and an optional ``attrs`` dict —
-``sp.set_attrs(flops=..., hbm_bytes=...)`` — the device-cost attribution
-the exporter forwards as trace-event args (DESIGN.md §14). A span whose
-body OR sync raises emits nothing: a failed device computation has no
-meaningful duration, and recording one would poison the stage histograms.
+``sp.set_attrs(...)`` — that the exporter forwards as trace-event args
+(DESIGN.md §14). A span whose body OR sync raises emits nothing: a
+failed device computation has no meaningful duration, and recording one
+would poison the stage histograms.
 """
 
 from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 
 class Span:
     """One timed stage; use via ``with tracker.span(name) as sp:``."""
 
     __slots__ = ("name", "tracer", "_sync", "t_start", "duration", "path",
-                 "depth", "attrs")
+                 "depth", "attrs", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, sync: Any = None,
                  attrs: Optional[Dict[str, Any]] = None):
@@ -46,6 +62,7 @@ class Span:
         self.path: Optional[str] = None
         self.depth: Optional[int] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
+        self._annotation = TraceAnnotation(name)
 
     def sync(self, value: Any) -> Any:
         """Register the value whose device completion ends this span;
@@ -59,6 +76,7 @@ class Span:
         self.attrs.update(attrs)
 
     def __enter__(self) -> "Span":
+        self._annotation.__enter__()
         self.tracer._push(self)
         self.t_start = self.tracer.tracker.clock()
         return self
@@ -80,6 +98,7 @@ class Span:
         finally:
             self.duration = self.tracer.tracker.clock() - self.t_start
             self.tracer._pop(self, failed=failed)
+            self._annotation.__exit__(exc_type, exc, tb)
 
 
 class Tracer:
@@ -122,13 +141,20 @@ class Tracer:
 
 
 class _NullSpan:
-    """No-tracker fast path: zero bookkeeping, ``sync`` is identity."""
+    """No-tracker path: the stage's profiler annotation and nothing else;
+    ``sync`` is the identity and ``set_attrs`` a no-op."""
 
-    def __enter__(self):
+    __slots__ = ("_annotation",)
+
+    def __init__(self, name: str):
+        self._annotation = TraceAnnotation(name)
+
+    def __enter__(self) -> "_NullSpan":
+        self._annotation.__enter__()
         return self
 
-    def __exit__(self, *exc):
-        return None
+    def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(*exc)
 
     @staticmethod
     def sync(value):
@@ -139,13 +165,10 @@ class _NullSpan:
         return None
 
 
-_NULL_SPAN = _NullSpan()
-
-
 def span_or_null(tracker, name: str, *, sync: Any = None):
-    """``tracker.span(name)`` when a tracker is attached, else a shared
-    no-op context — the instrumentation idiom for hot paths where
-    ``tracker`` is usually None."""
+    """``tracker.span(name)`` when a tracker is attached, else an
+    annotation-only span of the same name — the instrumentation idiom
+    for hot paths where ``tracker`` is usually None."""
     if tracker is None:
-        return _NULL_SPAN
+        return _NullSpan(name)
     return tracker.span(name, sync=sync)

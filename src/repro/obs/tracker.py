@@ -201,12 +201,13 @@ class Tracker:
         self._emit(rec)
 
     def span(self, name: str, *, sync: Any = None, attrs=None):
-        """Context manager timing a stage of the query hot path; see
+        """Context manager timing a stage of the query hot path inside a
+        profiler annotation of the same name; see
         :class:`repro.obs.trace.Tracer`. ``sync`` (or ``sp.sync(x)`` in
         the body) marks the device-sync boundary — the span blocks on it
         before reading the clock, so timings measure finished device work,
         not dispatch. ``attrs`` (or ``sp.set_attrs(...)``) attach
-        structured attributes — predicted flops/bytes — to the record."""
+        structured attributes to the record."""
         return self.tracer.span(name, sync=sync, attrs=attrs)
 
     # -- fleet rollup: per-shard trackers -> one view ------------------------

@@ -18,7 +18,7 @@ from repro.obs import (JsonlSink, LogHistogram, RecallAuditor,
                        RingBufferSink, StdoutTableSink, Tracker,
                        default_tracker, format_table, read_jsonl,
                        resolve_tracker, set_default_tracker, span_or_null)
-from repro.obs.trace import _NULL_SPAN
+from repro.obs.trace import _NullSpan
 
 KEY = jax.random.PRNGKey(5)
 
@@ -195,7 +195,10 @@ def test_span_sync_returns_value_unchanged():
     with span_or_null(None, "s") as sp:
         z = sp.sync(x)
     assert z is x
-    assert span_or_null(None, "anything") is _NULL_SPAN
+    # no tracker: a fresh annotation-only span per call, never a record
+    sp = span_or_null(None, "anything")
+    assert isinstance(sp, _NullSpan)
+    assert span_or_null(None, "anything") is not sp
 
 
 def test_span_exception_drops_record_and_unwinds():
@@ -434,6 +437,79 @@ def test_instrumented_query_ids_bit_identical(calibrated_index, engine):
                else "repro.engine.dense_match")
     assert stages <= set(t.hists)
     assert t.counters["repro.engine.queries"] == 2 * queries.shape[0]
+
+
+def _count_syncs(monkeypatch):
+    import jax as jax_mod
+    calls = []
+    real = jax_mod.block_until_ready
+
+    def counting(x):
+        calls.append(1)
+        return real(x)
+
+    monkeypatch.setattr(jax_mod, "block_until_ready", counting)
+    return calls
+
+
+@pytest.mark.parametrize("engine", ["bucket", "dense"])
+def test_untracked_spans_never_sync(calibrated_index, engine, monkeypatch):
+    """With no tracker a span is its profiler annotation and nothing else:
+    a whole query, every stage span included, makes no
+    ``block_until_ready`` call; attaching a tracker makes one per synced
+    stage."""
+    cidx, queries = calibrated_index
+    bare = QueryEngine(cidx, engine=engine)
+    inst = QueryEngine(cidx, engine=engine, tracker=Tracker())
+    bare.query(queries, 10, recall_target=0.9)
+    calls = _count_syncs(monkeypatch)
+    with span_or_null(None, "repro.engine.x") as sp:
+        sp.sync(jnp.ones((2,)))
+    bare.query(queries, 10, recall_target=0.9)
+    assert calls == []
+    inst.query(queries, 10, recall_target=0.9)
+    # hash_encode, match, planned_take, select, re_rank, top_k
+    assert len(calls) == 6
+
+
+@pytest.mark.parametrize("engine", ["bucket", "dense"])
+def test_query_ids_bit_identical_under_the_profiler(calibrated_index,
+                                                    engine, tmp_path):
+    """Tracing on (the profiler running, a tracker attached or not) leaves
+    ids and values bit-identical to a bare query."""
+    cidx, queries = calibrated_index
+    bare = QueryEngine(cidx, engine=engine)
+    inst = QueryEngine(cidx, engine=engine, tracker=Tracker())
+    v0, i0 = bare.query(queries, 10, recall_target=0.9)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        got = [e.query(queries, 10, recall_target=0.9)
+               for e in (bare, inst)]
+    finally:
+        jax.profiler.stop_trace()
+    for v1, i1 in got:
+        np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+        np.testing.assert_array_equal(np.asarray(v0), np.asarray(v1))
+
+
+def test_plan_span_inside_query_span(calibrated_index):
+    """Planning is timed inside the query: ``repro.engine.plan`` is a
+    child of ``repro.engine.query`` and only where a recall target is
+    resolved; ``planned_take`` is a child of the select stage."""
+    cidx, queries = calibrated_index
+    ring = RingBufferSink()
+    t = Tracker([ring])
+    for engine, select in (("bucket", "segmented_gather"),
+                           ("dense", "dense_select")):
+        eng = QueryEngine(cidx, engine=engine, tracker=t)
+        eng.query(queries, 10, recall_target=0.9)
+        paths = {r["path"] for r in ring.query(type="span")}
+        assert "repro.engine.query/repro.engine.plan" in paths
+        assert (f"repro.engine.query/repro.engine.{select}/"
+                "repro.engine.planned_take") in paths
+    n = t.hists["repro.engine.plan"].count
+    eng.query(queries, 10, 300)
+    assert t.hists["repro.engine.plan"].count == n == 2
 
 
 def test_instrumented_distributed_bit_identical(calibrated_index):
