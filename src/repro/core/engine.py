@@ -4,9 +4,13 @@ generation behind one front-end (DESIGN.md §5).
 Both engines realize Algorithm 2's probe order — the eq.-12 ranking of
 ``(range, match count)`` pairs — but with different cost shapes:
 
-  * ``engine="dense"`` — one packed Hamming scan over all N items, per-item
-    rank lookup, O(N log N) stable argsort. Best for small N or when the
-    bucket directory is nearly as large as the item table.
+  * ``engine="dense"`` — one Hamming scan over all N codes taken in CSR
+    order, where each range is a contiguous segment; each segment's
+    ranks by a select on the match count; one O(N log N) stable sort on
+    the key ``rank * R + range``, which also gives each slot's range, so
+    per-range budgets are selects too. No lookup gathers over the (Q, N)
+    slots. Best for small N or when the bucket directory is nearly as
+    large as the item table.
   * ``engine="bucket"`` — scan only the B-entry bucket directory
     (core/bucket_index.py), sort B bucket ranks, and gather the first
     ``num_probe`` items by walking the probe-ordered bucket runs
@@ -31,12 +35,14 @@ integer-hash families (L2-ALSH) traverse buckets too.
 
 from __future__ import annotations
 
+import functools
 from collections import OrderedDict
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import hashing
 from repro.core.bucket_index import BucketIndex, build_bucket_index
@@ -298,6 +304,100 @@ def quantize_payload(items_csr: jax.Array
     return payload, scale.astype(jnp.float32)
 
 
+def item_range_counts(range_id: jax.Array, num_ranges: int) -> np.ndarray:
+    """(R,) per-range item counts from the items' range ids (host)."""
+    return np.bincount(np.asarray(jax.device_get(range_id)),
+                       minlength=num_ranges).astype(np.int64)
+
+
+def _segment_bounds(range_counts: np.ndarray) -> Tuple[int, ...]:
+    """(R+1,) static CSR offsets of each range's segment: the store sorts
+    items by range first, so range j owns CSR positions
+    ``[bounds[j], bounds[j+1])``."""
+    return (0,) + tuple(int(c) for c in np.cumsum(range_counts))
+
+
+@functools.partial(jax.jit, static_argnames=("bounds",))
+def _dense_sort(matches_csr: jax.Array, rank: jax.Array,
+                bounds: Tuple[int, ...]) -> Tuple[jax.Array, jax.Array]:
+    """(sorted_key, order), both (Q, N): the CSR positions in canonical
+    ``(rank, CSR position)`` order and their keys ``rank * R + range``.
+
+    Each range's segment takes its rank by a (K+1)-way select on the
+    match count, so no (Q, N) gather touches the slots. The rank table is
+    a permutation of the ``(range, l)`` pairs, so a rank fixes its range:
+    the composite key sorts exactly as the rank does, and ``key % R``
+    recovers each slot's range after the sort."""
+    num_ranges = len(bounds) - 1
+    key_table = rank * num_ranges + jnp.arange(
+        num_ranges, dtype=jnp.int32)[:, None]                    # (R, K+1)
+    parts = []
+    for j in range(num_ranges):
+        m = matches_csr[:, bounds[j]:bounds[j + 1]]
+        if m.shape[1] == 0:
+            continue
+        key = jnp.full(m.shape, key_table[j, 0], jnp.int32)
+        for l in range(1, key_table.shape[1]):
+            key = jnp.where(m == l, key_table[j, l], key)
+        parts.append(key)
+    key = jnp.concatenate(parts, axis=1)
+    pos = lax.broadcasted_iota(jnp.int32, key.shape, 1)
+    return lax.sort_key_val(key, pos, dimension=1, is_stable=True)
+
+
+def _dense_order(buckets: BucketIndex, q_codes: jax.Array,
+                 db_codes: jax.Array, range_counts: np.ndarray, match_fn,
+                 tracker) -> Tuple[jax.Array, jax.Array]:
+    """The dense arms' shared match-and-rank stage: hash-match the
+    queries against the codes in CSR order (an (N, W) row gather, not
+    kept), then :func:`_dense_sort`. Returns (sorted_key, order)."""
+    bounds = _segment_bounds(range_counts)
+    if bounds[-1] != buckets.num_items:
+        raise ValueError(f"range counts cover {bounds[-1]} items, the "
+                         f"store holds {buckets.num_items}")
+    with span_or_null(tracker, "repro.engine.dense_match") as sp:
+        codes_csr = jnp.take(db_codes, buckets.item_ids, axis=0)
+        matches = match_fn(q_codes, codes_csr)                   # (Q, N)
+        return sp.sync(_dense_sort(matches, buckets.rank, bounds))
+
+
+# Query rows per step of _dense_keep's loop. The compiler keeps up to R of
+# range_cum_before's (rows, N) cumsums live at once, so a step's scratch
+# grows with its rows. On a v5e at N = 2^20, R = 32: 16 rows took 216 ms
+# and 2.1 GiB per 128 queries, 8 rows 1,098 ms, 32 rows 376 ms and 4.4
+# GiB; all 128 rows at once need more than the chip's 16 GB.
+_KEEP_ROWS = 16
+
+
+@functools.partial(jax.jit, static_argnames=("budgets",))
+def _dense_keep(sorted_key: jax.Array, budgets: Tuple[int, ...]
+                ) -> jax.Array:
+    """(Q, N) bool: the probe-ordered slots within their range's budget.
+    Each slot's range is its sort key mod R and its cap a select over the
+    R budgets; unit sizes make :func:`range_cum_before` the within-range
+    probe position."""
+    num_ranges = len(budgets)
+
+    def keep_row(key):
+        rid_o = key % num_ranges
+        wpos = range_cum_before(rid_o, jnp.ones_like(rid_o), num_ranges)
+        caps = jnp.zeros_like(rid_o)
+        for j, b in enumerate(budgets):
+            caps = jnp.where(rid_o == j, b, caps)
+        return wpos < caps
+
+    return lax.map(keep_row, sorted_key, batch_size=_KEEP_ROWS)
+
+
+@functools.partial(jax.jit, static_argnames=("total",))
+def _dense_take(keep: jax.Array, order: jax.Array, item_ids: jax.Array,
+                total: int) -> jax.Array:
+    """(Q, total) ids of the kept slots: exactly ``total`` per query, and
+    the stable sort pulls them to the front in canonical order."""
+    sel = jnp.argsort(~keep, axis=-1, stable=True)[:, :total]
+    return item_ids[jnp.take_along_axis(order, sel, axis=-1)]
+
+
 def planned_dense_candidates(buckets: BucketIndex, q_codes: jax.Array,
                              db_codes: jax.Array, range_id: jax.Array,
                              budgets: Sequence[int], *,
@@ -308,53 +408,39 @@ def planned_dense_candidates(buckets: BucketIndex, q_codes: jax.Array,
     :func:`planned_bucket_candidates` — identical candidate id sequences
     (tested by the conformance suite)."""
     if range_counts is None:
-        range_counts = np.bincount(
-            np.asarray(jax.device_get(range_id)),
-            minlength=buckets.rank.shape[0]).astype(np.int64)
+        range_counts = item_range_counts(range_id, buckets.num_ranges)
     budgets, total = check_budgets(budgets, range_counts)
     if match_fn is None:
         match_fn = _default_match(buckets, impl)
-    with span_or_null(tracker, "repro.engine.dense_match") as sp:
-        matches = match_fn(q_codes, db_codes)                    # (Q, N)
-        item_rank = buckets.rank[range_id[None, :], matches]
-        rank_csr = item_rank[:, buckets.item_ids]
-        order = sp.sync(
-            jnp.argsort(rank_csr, axis=-1, stable=True))         # (Q, N)
+    sorted_key, order = _dense_order(buckets, q_codes, db_codes,
+                                     range_counts, match_fn, tracker)
     with span_or_null(tracker, "repro.engine.dense_select") as sp:
-        rid_o = range_id[buckets.item_ids][order]
         with span_or_null(tracker, "repro.engine.planned_take") as sp_take:
-            # unit sizes make range_cum_before the within-range probe
-            # position
-            wpos = range_cum_before(rid_o, jnp.ones_like(rid_o),
-                                    len(budgets))
-            keep = sp_take.sync(
-                wpos < jnp.asarray(budgets, jnp.int32)[rid_o])
-        # exactly ``total`` kept per query; stable sort pulls them to the
-        # front in canonical order
-        sel = jnp.argsort(~keep, axis=-1, stable=True)[:, :total]
-        csr_pos = jnp.take_along_axis(order, sel, axis=-1)
-        return sp.sync(buckets.item_ids[csr_pos])
+            keep = sp_take.sync(_dense_keep(sorted_key, budgets))
+        del sorted_key  # free its (Q, N) before the take's sort
+        return sp.sync(_dense_take(keep, order, buckets.item_ids, total))
 
 
 def dense_candidates(buckets: BucketIndex, q_codes: jax.Array,
                      db_codes: jax.Array, range_id: jax.Array,
                      num_probe: int, *, impl: str = "auto",
-                     match_fn=None, tracker=None) -> jax.Array:
+                     match_fn=None,
+                     range_counts: Optional[np.ndarray] = None,
+                     tracker=None) -> jax.Array:
     """(Q, num_probe) candidate ids via the dense scan, in the same
     canonical ``(rank, CSR position)`` order as :func:`bucket_candidates`.
 
     Scores every item (O(Q N) match + O(N log N) sort); the bucket store is
     used only for the rank table and the CSR tie-break layout.
+    ``range_counts`` (host) skips the per-call sync for the segment bounds.
     """
     num_probe = int(num_probe)
+    if range_counts is None:
+        range_counts = item_range_counts(range_id, buckets.num_ranges)
     if match_fn is None:
         match_fn = _default_match(buckets, impl)
-    with span_or_null(tracker, "repro.engine.dense_match") as sp:
-        matches = match_fn(q_codes, db_codes)                    # (Q, N)
-        item_rank = buckets.rank[range_id[None, :], matches]
-        # reorder columns to CSR so the stable argsort ties on CSR position
-        rank_csr = item_rank[:, buckets.item_ids]
-        order = sp.sync(jnp.argsort(rank_csr, axis=-1, stable=True))
+    _, order = _dense_order(buckets, q_codes, db_codes, range_counts,
+                            match_fn, tracker)
     with span_or_null(tracker, "repro.engine.dense_select") as sp:
         return sp.sync(buckets.item_ids[order[:, :num_probe]])
 
@@ -529,7 +615,8 @@ class QueryEngine:
                                      match_fn=self._match_fn, tracker=tr)
         return dense_candidates(self.buckets, q_codes, self.index.codes,
                                 self._range_id, num_probe, impl=self.impl,
-                                match_fn=self._match_fn, tracker=tr)
+                                match_fn=self._match_fn,
+                                range_counts=self._range_counts, tracker=tr)
 
     def query(self, queries: jax.Array, k: int,
               num_probe: Optional[int] = None, *,
