@@ -321,7 +321,7 @@ def check_distributed_abstract(report: ContractReport, spec, items,
     abstract = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), concrete)
     with guard.forced():
-        vals_s, ids_s = jax.eval_shape(fn, *abstract)
+        vals_s, ids_s, _ = jax.eval_shape(fn, *abstract)
     _check_dtypes(report, distributed._shard_query,
                   "DistributedEngine collective (abstract)", vals_s,
                   ids_s)

@@ -27,11 +27,20 @@ the single-device path:
     is what makes the merged answer bit-identical to
     ``QueryEngine.query`` (tested). ``engine="dense"`` scans the local
     codes instead of walking runs (same probed set, dense cost shape).
+  * **bounded re-rank** (:func:`_blocked_rerank`): each shard gathers and
+    scores its owned slots ``RERANK_BLOCK`` at a time with a running
+    exact top-k, and stops at the batch's largest owned total — no
+    (Q, plan width, d) array, and a shard that owns little does little.
   * **merge**: per-shard exact top-k, one ``all_gather`` of
     ``(vals, ids)`` — O(k * shards) bytes on the interconnect — and a
     replicated re-top-k. Shards whose probed count falls short of ``k``
     pad with ``(-inf, -1)``, which can never displace a real candidate in
     the merge.
+
+The three phases carry the ``jax.named_scope`` names
+``repro.distributed.traverse``, ``.rerank`` and ``.merge``; the body also
+returns each shard's owned slots, which a tracked engine records as
+``repro.engine.distributed.owned_probes.shard<s>``.
 
 ``query_axis`` keeps the PR-era 2-D decomposition: queries shard over a
 second mesh axis, the Algorithm-2 merge all-gathers only across the item
@@ -46,6 +55,7 @@ budget ``min(N, num_probe_per_shard * num_shards)``.
 
 from __future__ import annotations
 
+import copy
 import functools
 from typing import Any, NamedTuple, Optional, Tuple
 
@@ -66,6 +76,8 @@ from repro.obs.trace import span_or_null
 from repro.obs.tracker import resolve_tracker
 
 ALIGNMENTS = ("bucket", "range")
+RERANK_BLOCK = 4096   # probed slots per step of a shard's re-rank loop
+OWNED_PROBES = "repro.engine.distributed.owned_probes"
 
 
 class ShardedIndex(NamedTuple):
@@ -134,7 +146,9 @@ def build_sharded(spec: IndexSpec, items: jax.Array, key: jax.Array,
                   num_shards: int, *, align: str = "bucket",
                   strict: bool = True,
                   calibration_queries: Optional[jax.Array] = None,
-                  calibration_k: Optional[int] = None) -> ShardedIndex:
+                  calibration_k: Optional[int] = None,
+                  mesh: Optional[Mesh] = None, axis="data",
+                  tracker=None) -> ShardedIndex:
     """Build the shard-aligned index for any spec (DESIGN.md §11).
 
     ``align="bucket"`` (default) splits at bucket boundaries balancing
@@ -144,6 +158,15 @@ def build_sharded(spec: IndexSpec, items: jax.Array, key: jax.Array,
     kwargs, DESIGN.md §12) happens on the pre-layout index — the
     calibrated canonical order is what every shard traverses — and the
     table rides replicated on the result.
+
+    With a ``mesh`` the calibration runs data-parallel over its devices
+    and the result comes back placed over ``axis`` (:func:`shard_index`).
+    The shard rows are gathered from the catalog on its device, so the
+    catalog never goes to the host, and the global index's device arrays
+    are dropped once the shards are placed. ``tracker`` times the phases
+    as synced spans: ``repro.build.index`` (hashing, partition and
+    calibration), ``.csr`` (the bucket store), ``.layout`` (the host
+    split) and ``.place``.
     """
     num_shards = int(num_shards)
     if num_shards < 1:
@@ -151,13 +174,38 @@ def build_sharded(spec: IndexSpec, items: jax.Array, key: jax.Array,
     if align not in ALIGNMENTS:
         raise ValueError(f"unknown align {align!r}; "
                          f"expected one of {ALIGNMENTS}")
-    cidx = build_spec(spec, items, key, strict=strict,
-                      calibration_queries=calibration_queries,
-                      calibration_k=calibration_k)
-    if isinstance(cidx, ComposedMultiTable):
-        raise ValueError("multi-table single-probe has no sharded path")
-    buckets = build_bucket_index(cidx)
+    with span_or_null(tracker, "repro.build.index") as sp:
+        cidx = build_spec(spec, items, key, strict=strict,
+                          calibration_queries=calibration_queries,
+                          calibration_k=calibration_k, mesh=mesh)
+        if isinstance(cidx, ComposedMultiTable):
+            raise ValueError("multi-table single-probe has no sharded path")
+        sp.sync(cidx.codes)
+    with span_or_null(tracker, "repro.build.csr"):
+        buckets = build_bucket_index(cidx)
+    with span_or_null(tracker, "repro.build.layout"):
+        host, src = _host_layout(spec, cidx, buckets, num_shards, align)
+    del buckets
+    with span_or_null(tracker, "repro.build.place") as sp:
+        rows = jnp.where(jnp.asarray(host.valid)[:, None],
+                         jnp.take(cidx.items, jnp.asarray(src, jnp.int32),
+                                  axis=0), 0)
+        del cidx
+        placed = host._replace(items=rows, **{
+            f: jnp.asarray(getattr(host, f)) for f in _ARRAY_FIELDS})
+        if mesh is not None:
+            placed = shard_index(placed, mesh, axis)
+        sp.sync(placed.items)
+    return placed
 
+
+def _host_layout(spec, cidx, buckets, num_shards: int, align: str
+                 ) -> ShardedIndex:
+    """The shard-aligned layout as host (numpy) arrays: the global CSR
+    order cut into ``num_shards`` spans at legal boundaries, each padded
+    to a common row count, and the replicated directory, with the item
+    rows left out; returns it and ``src``, the catalog row of each slot
+    (0 on padding)."""
     bstart = np.asarray(jax.device_get(buckets.bucket_start)).astype(
         np.int64)                                          # (B+1,)
     brid = np.asarray(jax.device_get(buckets.bucket_rid))
@@ -193,13 +241,8 @@ def build_sharded(spec: IndexSpec, items: jax.Array, key: jax.Array,
         bof[sl] = bucket_of_g[a:b]
         boff[sl] = off_g[a:b]
 
-    items_np = np.asarray(jax.device_get(cidx.items))
-    codes_np = np.asarray(jax.device_get(cidx.codes))
-    rid_np = np.asarray(jax.device_get(cidx.range_id))
-    items_sh = items_np[src]
-    codes_sh = codes_np[src]
-    rid_sh = rid_np[src].astype(np.int32)
-    items_sh[~valid] = 0
+    codes_sh = np.asarray(jax.device_get(cidx.codes))[src]
+    rid_sh = np.asarray(jax.device_get(cidx.range_id))[src].astype(np.int32)
     codes_sh[~valid] = 0
     rid_sh[~valid] = 0
 
@@ -211,24 +254,29 @@ def build_sharded(spec: IndexSpec, items: jax.Array, key: jax.Array,
         spec=spec,
         params=cidx.params,
         rank=rank_from_scores(cidx.table),
-        dir_code=buckets.bucket_code,
-        dir_rid=buckets.bucket_rid,
-        dir_size=jnp.asarray(sizes.astype(np.int32)),
-        dir_shard=jnp.asarray(dir_shard),
-        dir_local_start=jnp.asarray(dir_local_start),
-        items=jnp.asarray(items_sh),
-        codes=jnp.asarray(codes_sh),
-        range_id=jnp.asarray(rid_sh),
-        bucket_of=jnp.asarray(bof),
-        bucket_off=jnp.asarray(boff),
-        perm=jnp.asarray(perm),
-        valid=jnp.asarray(valid),
+        dir_code=np.asarray(jax.device_get(buckets.bucket_code)),
+        dir_rid=brid,
+        dir_size=sizes.astype(np.int32),
+        dir_shard=dir_shard,
+        dir_local_start=dir_local_start,
+        items=None,
+        codes=codes_sh,
+        range_id=rid_sh,
+        bucket_of=bof,
+        bucket_off=boff,
+        perm=perm,
+        valid=valid,
         num_shards=num_shards,
         rows_per_shard=rows,
         num_items=n,
         hash_bits=cidx.hash_bits,
         calib=cidx.calib,
-    )
+    ), src
+
+
+_ARRAY_FIELDS = ("rank", "dir_code", "dir_rid", "dir_size", "dir_shard",
+                 "dir_local_start", "codes", "range_id", "bucket_of",
+                 "bucket_off", "perm", "valid")
 
 
 def _axis_tuple(axis) -> Tuple[str, ...]:
@@ -273,118 +321,165 @@ def shard_index(index: ShardedIndex, mesh: Mesh, axis="data"
     )
 
 
+def _blocked_rerank(queries, items, positions, total, block, k):
+    """Exact local top-k over a shard's probed slots, ``block`` at a time.
+
+    ``positions(c)`` gives the (Q, block) local rows of slots ``[c *
+    block, (c + 1) * block)``; slots at or past a query's ``total`` are
+    masked. A ``while_loop`` runs only as many blocks as the batch's
+    largest owned total needs, so a shard that owns few of the planned
+    slots gathers and scores few rows, and no (Q, plan width, d) array
+    is ever made. The running top-k keeps earlier slots first on equal
+    scores, as one top-k over every slot in order would. Returns (vals,
+    rows), each (Q, k); rows of -inf values are meaningless."""
+    q = queries.shape[0]
+    slot = jnp.arange(block, dtype=jnp.int32)[None, :]
+    n_blocks = (jnp.max(total) + block - 1) // block
+
+    def body(state):
+        c, vals, rows = state
+        ok = c * block + slot < total[:, None]
+        # past its total a query's slots run beyond its covering run, so
+        # their rows are pinned in range before the gather
+        pos = jnp.where(ok, positions(c), 0)
+        ip = jnp.einsum("qd,qpd->qp", queries, items[pos], precision=EXACT)
+        ip = jnp.where(ok, ip, -jnp.inf)
+        vals, j = jax.lax.top_k(jnp.concatenate([vals, ip], axis=1), k)
+        rows = jnp.take_along_axis(jnp.concatenate([rows, pos], axis=1),
+                                   j, axis=1)
+        return c + 1, vals, rows
+
+    state = (jnp.int32(0), jnp.full((q, k), -jnp.inf, queries.dtype),
+             jnp.zeros((q, k), jnp.int32))
+    _, vals, rows = jax.lax.while_loop(lambda s: s[0] < n_blocks, body,
+                                       state)
+    return vals, rows
+
+
 def _shard_query(q_codes, queries, params, dir_code, dir_rid, dir_size,
                  dir_shard, dir_lstart, rank, items, codes, range_id,
                  bucket_of, bucket_off, perm, valid, *, family, hash_bits,
                  num_probe, k, engine, impl, axis, axis_sizes, query_axis,
-                 budgets=None):
+                 budgets=None, block=None):
     """Per-shard body: global directory traversal -> local probe of the
     owned slice of the canonical first-``num_probe`` items (or, with
     ``budgets``, of the planner's per-range prefixes totalling
-    ``num_probe``) -> exact local top-k -> Algorithm-2 all_gather merge."""
+    ``num_probe``) -> exact local top-k, ``block`` slots at a time ->
+    Algorithm-2 all_gather merge. Also returns the slots this shard owns
+    over the batch, (1,) per shard."""
     my = jnp.int32(0)
     for a, s in zip(axis, axis_sizes):
         my = my * s + jax.lax.axis_index(a)
-
-    # global bucket probe order, identical on every shard (replicated
-    # inputs): matches -> rank -> stable argsort -> per-bucket take under
-    # the global budget.
-    matches = family.match_counts(params, q_codes, dir_code, hash_bits,
-                                  impl=impl)                  # (Q, B)
-    brank = rank[dir_rid[None, :], matches]
-    order = jnp.argsort(brank, axis=-1, stable=True)          # (Q, B)
     q_local = q_codes.shape[0]
     # a shard re-ranks at most its own rows, whatever the global budget
     width = min(num_probe, codes.shape[0])
+    block = width if block is None else min(int(block), width)
 
-    if engine == "bucket":
-        # walk only the owned buckets' runs: O(B log B) directory work +
-        # O(num_probe) gather, never the O(rows) item table. Every bucket
-        # holds >= 1 item, so the first min(B, P) probe-ordered buckets
-        # cover a global budget (the single-device slice, engine.py);
-        # per-range budgets can land anywhere, so they walk the full
-        # directory.
-        if budgets is not None:
-            sel = order
-            sizes_o = dir_size[sel]
-            take = planned_take(dir_rid[order], sizes_o, budgets)
+    with jax.named_scope("repro.distributed.traverse"):
+        # global bucket probe order, identical on every shard (replicated
+        # inputs): matches -> rank -> stable argsort -> per-bucket take
+        # under the global budget.
+        matches = family.match_counts(params, q_codes, dir_code,
+                                      hash_bits, impl=impl)   # (Q, B)
+        brank = rank[dir_rid[None, :], matches]
+        order = jnp.argsort(brank, axis=-1, stable=True)      # (Q, B)
+
+        if engine == "bucket":
+            # walk only the owned buckets' runs: O(B log B) directory
+            # work + O(owned) gather, never the O(rows) item table. Every
+            # bucket holds >= 1 item, so the first min(B, P)
+            # probe-ordered buckets cover a global budget (the
+            # single-device slice, engine.py); per-range budgets can land
+            # anywhere, so they walk the full directory.
+            if budgets is not None:
+                sel = order
+                sizes_o = dir_size[sel]
+                take = planned_take(dir_rid[order], sizes_o, budgets)
+            else:
+                sel = order[:, :min(order.shape[1], num_probe)]
+                sizes_o = dir_size[sel]
+                cum = jnp.cumsum(sizes_o, axis=-1, dtype=jnp.int32)
+                take = jnp.clip(num_probe - (cum - sizes_o), 0, sizes_o)
+            owned = dir_shard[sel] == my
+            ltake = jnp.where(owned, take, 0)
+            lcum = jnp.cumsum(ltake, axis=-1, dtype=jnp.int32)
+            total = lcum[:, -1]                               # (Q,)
+            # a covering run keeps each block's gather in-contract past
+            # ``total``; its slots are masked in the re-rank.
+            cum2 = jnp.concatenate(
+                [jnp.zeros((q_local, 1), jnp.int32), lcum,
+                 lcum[:, -1:] + jnp.int32(block)], axis=1)
+            starts2 = jnp.concatenate(
+                [dir_lstart[sel], jnp.zeros((q_local, 1), jnp.int32)],
+                axis=1)
+
+            def positions(c):
+                # runs shifted to the block's first slot: earlier runs
+                # end at or before 0, so slot p of the block is slot
+                # c * block + p of the shard's probe walk
+                return ops.bucket_gather(cum2 - c * block, starts2, block,
+                                         impl=impl)
         else:
-            sel = order[:, :min(order.shape[1], num_probe)]
-            sizes_o = dir_size[sel]
-            cum = jnp.cumsum(sizes_o, axis=-1, dtype=jnp.int32)
-            take = jnp.clip(num_probe - (cum - sizes_o), 0, sizes_o)
-        owned = dir_shard[sel] == my
-        ltake = jnp.where(owned, take, 0)
-        lcum = jnp.cumsum(ltake, axis=-1, dtype=jnp.int32)
-        total = lcum[:, -1]                                   # (Q,)
-        starts_o = dir_lstart[sel]
-        # a covering run keeps the gather in-contract past ``total``;
-        # its slots are masked below.
-        cum2 = jnp.concatenate(
-            [jnp.zeros((q_local, 1), jnp.int32), lcum,
-             lcum[:, -1:] + jnp.int32(width)], axis=1)
-        starts2 = jnp.concatenate(
-            [starts_o, jnp.zeros((q_local, 1), jnp.int32)], axis=1)
-        pos = ops.bucket_gather(cum2, starts2, width, impl=impl)
-    else:
-        # dense arm: score every local row, keep rows whose canonical
-        # position (items before its bucket + in-bucket offset — global
-        # under a scalar budget, within-range under planned budgets) is
-        # under the budget — the same probed set as the bucket arm.
-        # The position scatter needs the cumulative sizes of ALL buckets.
-        md = family.match_counts(params, q_codes, codes, hash_bits,
-                                 impl=impl)                   # (Q, rows)
-        irank = rank[range_id[None, :], md]
-        if budgets is not None:
-            crb = range_cum_before(dir_rid[order], dir_size[order],
-                                   len(budgets))
-            cpb = jnp.zeros_like(crb).at[
-                jnp.arange(q_local)[:, None], order].set(crb)
-            wpos = cpb[:, bucket_of] + bucket_off[None, :]
-            cap = jnp.asarray(budgets, jnp.int32)[range_id]
-            probed = valid[None, :] & (wpos < cap[None, :])
-        else:
-            sizes_o = dir_size[order]
-            cum = jnp.cumsum(sizes_o, axis=-1, dtype=jnp.int32)
-            cum_prev = cum - sizes_o
-            cpb = jnp.zeros_like(cum_prev).at[
-                jnp.arange(q_local)[:, None], order].set(cum_prev)
-            gpos = cpb[:, bucket_of] + bucket_off[None, :]
-            probed = valid[None, :] & (gpos < num_probe)
-        key = jnp.where(probed, irank, jnp.iinfo(jnp.int32).max)
-        order_l = jnp.argsort(key, axis=-1, stable=True)
-        pos = order_l[:, :width]
-        total = jnp.sum(probed.astype(jnp.int32), axis=-1)
+            # dense arm: score every local row, keep rows whose canonical
+            # position (items before its bucket + in-bucket offset —
+            # global under a scalar budget, within-range under planned
+            # budgets) is under the budget — the same probed set as the
+            # bucket arm. The position scatter needs the cumulative sizes
+            # of ALL buckets.
+            md = family.match_counts(params, q_codes, codes, hash_bits,
+                                     impl=impl)               # (Q, rows)
+            irank = rank[range_id[None, :], md]
+            if budgets is not None:
+                crb = range_cum_before(dir_rid[order], dir_size[order],
+                                       len(budgets))
+                cpb = jnp.zeros_like(crb).at[
+                    jnp.arange(q_local)[:, None], order].set(crb)
+                wpos = cpb[:, bucket_of] + bucket_off[None, :]
+                cap = jnp.asarray(budgets, jnp.int32)[range_id]
+                probed = valid[None, :] & (wpos < cap[None, :])
+            else:
+                sizes_o = dir_size[order]
+                cum = jnp.cumsum(sizes_o, axis=-1, dtype=jnp.int32)
+                cum_prev = cum - sizes_o
+                cpb = jnp.zeros_like(cum_prev).at[
+                    jnp.arange(q_local)[:, None], order].set(cum_prev)
+                gpos = cpb[:, bucket_of] + bucket_off[None, :]
+                probed = valid[None, :] & (gpos < num_probe)
+            key = jnp.where(probed, irank, jnp.iinfo(jnp.int32).max)
+            order_l = jnp.argsort(key, axis=-1, stable=True)
+            n_pad = -(-width // block) * block
+            pos_all = order_l[:, :width].astype(jnp.int32)
+            if n_pad > width:
+                pos_all = jnp.pad(pos_all, ((0, 0), (0, n_pad - width)))
+            total = jnp.sum(probed.astype(jnp.int32), axis=-1)
 
-    slot_ok = jnp.arange(width, dtype=jnp.int32)[None, :] < total[:, None]
-    cand = items[pos]                                         # (Q, P, d)
-    ip = jnp.einsum("qd,qpd->qp", queries, cand, precision=EXACT)
-    ip = jnp.where(slot_ok, ip, -jnp.inf)
-    if width < k:        # a shard smaller than k still merges cleanly
-        ip = jnp.concatenate(
-            [ip, jnp.full((q_local, k - width), -jnp.inf, ip.dtype)],
-            axis=1)
-        pos = jnp.concatenate(
-            [pos, jnp.zeros((q_local, k - width), pos.dtype)], axis=1)
-    lvals, lpos = jax.lax.top_k(ip, k)
-    lids = perm[jnp.take_along_axis(pos, lpos, axis=1)]
-    # padded/tombstone slots must not leak ids into the merge
-    lids = jnp.where(lvals == -jnp.inf, -1, lids)
+            def positions(c):
+                return jax.lax.dynamic_slice_in_dim(pos_all, c * block,
+                                                    block, axis=1)
 
-    av = jax.lax.all_gather(lvals, axis)                      # (S, Q, k)
-    ai = jax.lax.all_gather(lids, axis)
-    s_all, q_all, kk = av.shape
-    fv = jnp.transpose(av, (1, 0, 2)).reshape(q_all, s_all * kk)
-    fi = jnp.transpose(ai, (1, 0, 2)).reshape(q_all, s_all * kk)
-    bv, bp = jax.lax.top_k(fv, k)
-    bi = jnp.take_along_axis(fi, bp, axis=1)
-    bi = jnp.where(bv == -jnp.inf, -1, bi)
-    if query_axis is not None:   # restore the full replicated (Q, k)
-        gv = jax.lax.all_gather(bv, query_axis)
-        gi = jax.lax.all_gather(bi, query_axis)
-        bv = gv.reshape(-1, k)
-        bi = gi.reshape(-1, k)
-    return bv, bi
+    with jax.named_scope("repro.distributed.rerank"):
+        lvals, lrows = _blocked_rerank(queries, items, positions, total,
+                                       block, k)
+        # padded/tombstone slots must not leak ids into the merge
+        lids = jnp.where(lvals == -jnp.inf, -1, perm[lrows])
+
+    with jax.named_scope("repro.distributed.merge"):
+        av = jax.lax.all_gather(lvals, axis)                  # (S, Q, k)
+        ai = jax.lax.all_gather(lids, axis)
+        s_all, q_all, kk = av.shape
+        fv = jnp.transpose(av, (1, 0, 2)).reshape(q_all, s_all * kk)
+        fi = jnp.transpose(ai, (1, 0, 2)).reshape(q_all, s_all * kk)
+        bv, bp = jax.lax.top_k(fv, k)
+        bi = jnp.take_along_axis(fi, bp, axis=1)
+        bi = jnp.where(bv == -jnp.inf, -1, bi)
+        owned_slots = jnp.sum(total)[None]
+        if query_axis is not None:   # restore the full replicated (Q, k)
+            gv = jax.lax.all_gather(bv, query_axis)
+            gi = jax.lax.all_gather(bi, query_axis)
+            bv = gv.reshape(-1, k)
+            bi = gi.reshape(-1, k)
+            owned_slots = jax.lax.psum(owned_slots, query_axis)
+    return bv, bi, owned_slots
 
 
 class DistributedEngine:
@@ -405,11 +500,18 @@ class DistributedEngine:
               (2-D decomposition; merge traffic drops by its size).
       tracker: optional :class:`repro.obs.Tracker` (None = ambient
               default). Records encode/collective spans, query counters,
-              and jitted-collective cache hit/miss + trace-count — all
-              host-side, outside the shard_map, so results stay
-              bit-identical (parity-tested). Stage timings inside the
-              collective are not separable (one jitted program); the
-              collective span measures it end-to-end.
+              the slots each shard owns per batch
+              (``repro.engine.distributed.owned_probes.shard<s>``, slots
+              per query) and jitted-collective cache hit/miss +
+              trace-count — all host-side, outside the shard_map, so
+              results stay bit-identical (parity-tested).
+
+    The collective is one jitted program; its phases carry the
+    ``jax.named_scope`` names ``repro.distributed.traverse`` (directory
+    match, probe order, planned takes, the dense scan),
+    ``repro.distributed.rerank`` (the blocked gather, score and running
+    top-k) and ``repro.distributed.merge`` (the ``all_gather`` and the
+    replicated top-k), which the device trace's op names carry.
     """
 
     def __init__(self, index: ShardedIndex, mesh: Mesh, *,
@@ -444,6 +546,14 @@ class DistributedEngine:
         self._mapped_cache = {}
         self._range_counts_cache = None
 
+    def with_tracker(self, tracker) -> "DistributedEngine":
+        """The same index behind an engine that reports to ``tracker``.
+        It shares this engine's jitted collectives, so a batch shape
+        warmed on one is warm on both and the programs are the same."""
+        eng = copy.copy(self)
+        eng.tracker = tracker
+        return eng
+
     @property
     def _range_counts(self) -> np.ndarray:
         """Global per-range item counts from the replicated directory —
@@ -476,7 +586,8 @@ class DistributedEngine:
             _shard_query, family=self.family, hash_bits=idx.hash_bits,
             num_probe=num_probe, k=k, engine=self.engine,
             impl=self.impl, axis=self.axis, axis_sizes=axis_sizes,
-            query_axis=self.query_axis, budgets=budgets)
+            query_axis=self.query_axis, budgets=budgets,
+            block=RERANK_BLOCK)
         q2 = P(self.query_axis, None) if self.query_axis \
             else P(None, None)
         row = P(self.axis)
@@ -486,7 +597,7 @@ class DistributedEngine:
             body, mesh=self.mesh,
             in_specs=(q2, q2, params_spec, P(), P(), P(), P(), P(), P(),
                       row2, row2, row, row, row, row, row),
-            out_specs=(P(), P()),
+            out_specs=(P(), P(), P(self.axis)),
             check_vma=False,
         ))
         self._mapped_cache[key] = fn
@@ -543,7 +654,7 @@ class DistributedEngine:
         # NOTE: re-rank uses the ORIGINAL queries (true inner products);
         # the family transform only affects the hash codes.
         with span_or_null(tr, "repro.engine.distributed.collective") as sp:
-            vals, ids = sp.sync(mapped(
+            vals, ids, owned = sp.sync(mapped(
                 q_codes, queries, idx.params, idx.dir_code,
                 idx.dir_rid, idx.dir_size, idx.dir_shard,
                 idx.dir_local_start, idx.rank, idx.items, idx.codes,
@@ -552,6 +663,9 @@ class DistributedEngine:
         if tr is not None:
             tr.count("repro.engine.queries", queries.shape[0])
             tr.observe("repro.engine.probe_width", num_probe)
+            per_query = np.asarray(owned) / queries.shape[0]
+            for s, v in enumerate(per_query):
+                tr.observe(f"{OWNED_PROBES}.shard{s}", float(v))
             if budgets is not None:
                 for j, b in enumerate(budgets):
                     tr.observe(f"repro.engine.probes_used.range{j}", b)

@@ -433,7 +433,7 @@ def _partition(norms: jax.Array, spec: IndexSpec):
 def build(spec: IndexSpec, items: jax.Array, key: jax.Array, *,
           num_shards: Optional[int] = None, strict: bool = True,
           calibration_queries: Optional[jax.Array] = None,
-          calibration_k: Optional[int] = None):
+          calibration_k: Optional[int] = None, mesh=None):
     """Spec-driven index construction — the single entry point.
 
     Returns a :class:`ComposedIndex` (or :class:`ComposedMultiTable` when
@@ -448,12 +448,14 @@ def build(spec: IndexSpec, items: jax.Array, key: jax.Array, *,
     §12): held-out queries — ``calibration_queries`` or standard-normal
     samples drawn from ``key`` — are measured against brute-force ground
     truth and the fitted :class:`~repro.core.planner.CalibrationTable`
-    rides on the index, powering ``query(recall_target=...)``."""
+    rides on the index, powering ``query(recall_target=...)``. With a
+    ``mesh`` calibration runs data-parallel over its devices, and the
+    shard-aligned path places its shards over it."""
     if num_shards is not None:
         from repro.core.distributed import build_sharded
         return build_sharded(spec, items, key, num_shards, strict=strict,
                              calibration_queries=calibration_queries,
-                             calibration_k=calibration_k)
+                             calibration_k=calibration_k, mesh=mesh)
     spec.validate(strict=strict)
     fam = spec.resolve_family()
     items = jnp.asarray(items)
@@ -486,5 +488,5 @@ def build(spec: IndexSpec, items: jax.Array, key: jax.Array, *,
             cidx, calibration_queries,
             k=(planner.DEFAULT_CAL_K if calibration_k is None
                else int(calibration_k)),
-            key=jax.random.fold_in(key, 0x5ca1)))
+            key=jax.random.fold_in(key, 0x5ca1), mesh=mesh))
     return cidx

@@ -48,6 +48,7 @@ queries stay on the jit cache like static-budget ones.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -59,6 +60,9 @@ from repro.core import hashing, topk
 DEFAULT_CAL_QUERIES = 256
 DEFAULT_CAL_K = 10
 GRID_FACTOR = 1.3
+# calibration queries each device holds at once: a (CAL_BLOCK, N) working
+# set of about 24 bytes per query and item, 3 GB at N = 2^22
+CAL_BLOCK = 32
 
 
 class CalibrationTable(NamedTuple):
@@ -150,14 +154,9 @@ def calibrate_from_order(order_ids: np.ndarray, range_id: np.ndarray,
     range_id = np.asarray(range_id, np.int64)
     truth_ids = np.asarray(truth_ids)
     q, n = order_ids.shape
-    k = truth_ids.shape[1]
     if num_ranges is None:
         num_ranges = int(range_id.max()) + 1 if range_id.size else 1
     m = int(num_ranges)
-    counts = np.bincount(range_id, minlength=m).astype(np.int64)
-    if grid is None:
-        grid = default_grid(n)
-    grid = np.asarray(grid, np.int64)
 
     # global position of every id, and its position within its range's
     # probe order (cumulative count of same-range items before it)
@@ -178,9 +177,31 @@ def calibrate_from_order(order_ids: np.ndarray, range_id: np.ndarray,
     group_start = np.maximum.accumulate(np.where(first, idx, 0), axis=1)
     wpos_sorted = np.empty((q, n), np.int64)
     wpos_sorted[rows, by_range] = idx - group_start
-    t_wpos = np.take_along_axis(wpos_sorted, t_gpos, axis=1).reshape(-1)
+    t_wpos = np.take_along_axis(wpos_sorted, t_gpos, axis=1)
+    return calibrate_from_positions(t_gpos, t_wpos, range_id[truth_ids],
+                                    np.bincount(range_id, minlength=m),
+                                    grid=grid)
+
+
+def calibrate_from_positions(t_gpos: np.ndarray, t_wpos: np.ndarray,
+                             t_rid: np.ndarray, range_counts: np.ndarray,
+                             *, grid: Optional[np.ndarray] = None
+                             ) -> CalibrationTable:
+    """Fit the table from where each truth item sits in canonical probe
+    order: ``t_gpos`` (Q, k) its global position, ``t_wpos`` (Q, k) its
+    position among its own range's items, ``t_rid`` (Q, k) its range;
+    ``range_counts`` (R,) items per range. Every calibration path ends
+    here, so equal positions give a bit-identical table."""
+    t_gpos = np.asarray(t_gpos, np.int64)
+    q, k = t_gpos.shape
+    counts = np.asarray(range_counts, np.int64)
+    m = counts.shape[0]
+    if grid is None:
+        grid = default_grid(int(counts.sum()))
+    grid = np.asarray(grid, np.int64)
     t_gpos = t_gpos.reshape(-1)
-    t_rid = range_id[truth_ids.reshape(-1)]
+    t_wpos = np.asarray(t_wpos, np.int64).reshape(-1)
+    t_rid = np.asarray(t_rid, np.int64).reshape(-1)
     total = t_rid.size
 
     recall_global = (t_gpos[None, :] < grid[:, None]).mean(
@@ -222,18 +243,76 @@ def canonical_order(index, queries: jax.Array, *, buckets=None
     return np.asarray(jax.device_get(buckets.item_ids))[order]
 
 
+def _truth_positions(queries, params, items, codes, range_id, csr_pos,
+                     rank, *, family, hash_bits, impl, k, block):
+    """Where each exact top-``k`` item of each query sits in canonical
+    ``(rank, CSR position)`` probe order, globally and within its range,
+    counted on the device in blocks of ``block`` queries: an item's
+    position is the number of items whose (rank, CSR position) is
+    smaller, so no (b, N) order is ever sorted or brought to the host.
+    Returns (gpos, wpos, truth), each (Q, k) int32."""
+
+    def one(q):
+        q_codes = family.encode_queries(params, q, impl=impl)
+        matches = family.match_counts(params, q_codes, codes, hash_bits,
+                                      impl=impl)                # (b, N)
+        item_rank = rank[range_id[None, :], matches]
+        _, truth = topk.exact_mips(q, items, k)                 # (b, k)
+        t_rank = jnp.take_along_axis(item_rank, truth, axis=1)
+        t_csr = csr_pos[truth]
+        t_rid = range_id[truth]
+
+        def count(j):
+            r, c = t_rank[:, j, None], t_csr[:, j, None]
+            before = (item_rank < r) | ((item_rank == r)
+                                        & (csr_pos[None, :] < c))
+            same = range_id[None, :] == t_rid[:, j, None]
+            return (jnp.sum(before, axis=1, dtype=jnp.int32),
+                    jnp.sum(before & same, axis=1, dtype=jnp.int32))
+
+        gpos, wpos = jax.lax.map(count, jnp.arange(k))          # (k, b)
+        return gpos.T, wpos.T, truth.astype(jnp.int32)
+
+    q, d = queries.shape
+    out = jax.lax.map(one, queries.reshape(q // block, block, d))
+    return tuple(x.reshape(q, k) for x in out)
+
+
+@functools.lru_cache(maxsize=8)
+def _truth_positions_mesh(mesh, **static):
+    """:func:`_truth_positions` data-parallel over every device of
+    ``mesh``: queries split evenly, everything else replicated."""
+    from jax.sharding import PartitionSpec as P
+    rows = P(tuple(mesh.axis_names))
+    return jax.jit(jax.shard_map(
+        functools.partial(_truth_positions, **static), mesh=mesh,
+        in_specs=(rows,) + (P(),) * 6, out_specs=rows, check_vma=False))
+
+
 def calibrate(index, queries: Optional[jax.Array] = None, *,
               k: int = DEFAULT_CAL_K, key: Optional[jax.Array] = None,
               num_queries: int = DEFAULT_CAL_QUERIES,
               grid: Optional[np.ndarray] = None,
-              buckets=None) -> CalibrationTable:
+              buckets=None, mesh=None) -> CalibrationTable:
     """Calibrate a spec-built :class:`~repro.core.index.ComposedIndex`.
 
     ``queries`` should be held-out samples of the serving distribution;
     when absent, standard-normal queries are drawn from ``key`` (the
     synthetic-dataset query model — override for real workloads). Ground
     truth is brute force, so this is an offline O(Q N) step.
+
+    Each device takes ``CAL_BLOCK`` queries at a time, so the (block, N)
+    working set stays bounded at any catalog size. With a ``mesh`` the
+    catalog is replicated over its devices for the call and the blocks
+    run data-parallel, each device taking an equal share of the queries;
+    without one, the first device is a one-device mesh. The table is
+    bit-identical whatever the block or the mesh: each truth item's
+    position is an integer count, and the fit is one host pass.
     """
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.bucket_index import build_bucket_index
+
     if queries is None:
         if key is None:
             raise ValueError("pass calibration queries or a key to "
@@ -244,12 +323,37 @@ def calibrate(index, queries: Optional[jax.Array] = None, *,
     n = int(index.items.shape[0])
     if not 0 < int(k) <= n:
         raise ValueError(f"calibration k={k} outside (0, N={n}]")
-    order_ids = canonical_order(index, queries, buckets=buckets)
-    _, truth = topk.exact_mips(queries, index.items, k)
-    return calibrate_from_order(
-        order_ids, np.asarray(jax.device_get(index.range_id)),
-        np.asarray(jax.device_get(truth)),
-        num_ranges=int(index.table.shape[0]), grid=grid)
+    if buckets is None:
+        buckets = build_bucket_index(index)
+    ids = np.asarray(jax.device_get(buckets.item_ids))
+    csr_pos = np.empty((n,), np.int32)
+    csr_pos[ids] = np.arange(n, dtype=np.int32)
+    if mesh is None:
+        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    q = int(queries.shape[0])
+    per_dev = -(-q // mesh.devices.size)
+    block = min(int(CAL_BLOCK), per_dev)
+    padded = mesh.devices.size * -(-per_dev // block) * block
+    if padded > q:       # repeat a real query; padded rows are dropped
+        queries = jnp.concatenate(
+            [queries, jnp.broadcast_to(queries[:1], (padded - q,
+                                                     queries.shape[1]))])
+    rep = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+    args = (jax.device_put(queries, rows),) + jax.tree.map(
+        lambda x: jax.device_put(x, rep),
+        (index.params, index.items, index.codes, index.range_id,
+         jnp.asarray(csr_pos), buckets.rank))
+    out = _truth_positions_mesh(
+        mesh, family=index.family, hash_bits=index.hash_bits,
+        impl=index.spec.impl, k=int(k), block=block)(*args)
+    gpos, wpos, truth = (np.asarray(x)[:q] for x in jax.device_get(out))
+    del args, out
+    range_id = np.asarray(jax.device_get(index.range_id), np.int64)
+    num_ranges = int(index.table.shape[0])
+    return calibrate_from_positions(
+        gpos, wpos, range_id[truth],
+        np.bincount(range_id, minlength=num_ranges), grid=grid)
 
 
 def calibrate_streaming(mindex, queries: jax.Array, *,

@@ -263,6 +263,173 @@ def test_sharded_parity_on_8_devices():
     assert "SUBPROCESS_OK" in out.stdout, out.stderr[-2000:]
 
 
+# -- 4-way subprocess: the planned path at ImageNet's widths ------------------
+
+
+FOUR_SHARD_PLANNED = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import Mesh
+    from repro.core import distributed, planner, topk
+    from repro.core.engine import QueryEngine
+    from repro.core.index import IndexSpec, build
+    from repro.obs import Tracker
+
+    N, D, K, BLOCK = 1 << 14, 150, 10, 64
+    kd, kn, kq, kc = jax.random.split(jax.random.PRNGKey(15), 4)
+    x = jax.random.normal(kd, (N, D))
+    items = x / jnp.linalg.norm(x, axis=1, keepdims=True) * jnp.exp(
+        0.8 * jax.random.normal(kn, (N,)))[:, None]
+    queries = jax.random.normal(kq, (16, D))
+    mesh = Mesh(np.array(jax.devices()), ("data",))
+    spec = IndexSpec(family="simple", code_len=16, m=32, recall_target=0.9)
+    key = jax.random.PRNGKey(3)
+    out = {}
+
+    # calibration: one block, small blocks, the mesh, all against the
+    # unblocked canonical order fitted on the host
+    cidx = build(spec, items, key)
+    cal_q = jax.random.normal(kc, (256, D))
+    _, truth = topk.exact_mips(cal_q, items, K)
+    want = planner.calibrate_from_order(
+        planner.canonical_order(cidx, cal_q), np.asarray(cidx.range_id),
+        np.asarray(truth), num_ranges=int(cidx.table.shape[0]))
+    def same(a, b):
+        return all(np.array_equal(np.asarray(getattr(a, f)),
+                                  np.asarray(getattr(b, f)))
+                   and np.asarray(getattr(a, f)).dtype
+                   == np.asarray(getattr(b, f)).dtype for f in a._fields)
+    out["calibration"] = {}
+    for blk in (None, 48):
+        if blk:      # a block that does not divide a device's share
+            planner.CAL_BLOCK = blk
+        for m in (None, mesh):
+            out["calibration"][f"{blk}/{'mesh' if m else 'one'}"] = same(
+                want, planner.calibrate(cidx, cal_q, k=K, mesh=m))
+
+    distributed.RERANK_BLOCK = BLOCK
+
+    sidx = distributed.build_sharded(spec, items, key, 4, mesh=mesh)
+    truth_q = np.asarray(jnp.argsort(-jnp.matmul(
+        queries, items.T, precision=jax.lax.Precision.HIGHEST),
+        axis=1)[:, :K])
+    width = planner.resolve_budgets(sidx.calib, 0.9, k=K).num_probe
+    out["width"], out["block"] = width, BLOCK
+    out["arms"] = {}
+    for arm in ("bucket", "dense"):
+        wv, wi = QueryEngine(cidx, engine=arm).query(queries, K,
+                                                     recall_target=0.9)
+        t = Tracker()
+        eng = distributed.DistributedEngine(sidx, mesh, engine=arm)
+        gv, gi = eng.with_tracker(t).query(queries, K, recall_target=0.9)
+        gi, gv = np.asarray(gi), np.asarray(gv)
+        rows = np.asarray(items, np.float64)[gi]
+        exact = np.einsum("qd,qkd->qk", np.asarray(queries, np.float64),
+                          rows)
+        scale = (np.linalg.norm(np.asarray(queries, np.float64), axis=1)
+                 [:, None] * np.linalg.norm(rows, axis=2))
+        owned = [t.hists[f"{distributed.OWNED_PROBES}.shard{s}"].total
+                 for s in range(4)]
+        out["arms"][arm] = {
+            "ids_equal": bool(np.array_equal(gi, np.asarray(wi))),
+            "recall": float(np.mean([len(set(a) & set(b)) / K
+                                     for a, b in zip(gi, truth_q)])),
+            "score_rel_err": float(np.max(np.abs(gv - exact) / scale)),
+            "owned": owned,
+            "untracked_equal": bool(np.array_equal(
+                np.asarray(eng.query(queries, K, recall_target=0.9)[1]),
+                gi)),
+        }
+
+    # no (Q, plan width, d) array in the collective: only (Q, block, d)
+    def shapes(jaxpr):
+        for eqn in jaxpr.eqns:
+            for v in eqn.outvars:
+                yield tuple(getattr(v.aval, "shape", ()))
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from shapes(inner)
+    budgets = planner.resolve_budgets(sidx.calib, 0.9, k=K).budgets
+    Q = queries.shape[0]
+    for block in (BLOCK, None):
+        distributed.RERANK_BLOCK = block or N
+        eng = distributed.DistributedEngine(sidx, mesh, engine="bucket")
+        q_codes = eng._encode(sidx.params, queries)
+        jx = jax.make_jaxpr(eng._mapped(width, K, budgets))(
+            q_codes, queries, sidx.params, sidx.dir_code, sidx.dir_rid,
+            sidx.dir_size, sidx.dir_shard, sidx.dir_local_start, sidx.rank,
+            sidx.items, sidx.codes, sidx.range_id, sidx.bucket_of,
+            sidx.bucket_off, sidx.perm, sidx.valid)
+        out["qpd_" + ("block" if block else "plan")] = sorted(
+            {s[1] for s in shapes(jx.jaxpr)
+             if len(s) == 3 and s[0] == Q and s[2] == D})
+    print("FOUR_SHARD_JSON " + json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def four_shard_planned():
+    """The four-shard planned path at N = 2^14, d = 150, L = 16, m = 32
+    with lognormal norms, run once on four forced host devices."""
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    out = subprocess.run([sys.executable, "-c", FOUR_SHARD_PLANNED],
+                         capture_output=True, text=True, env=env,
+                         timeout=300)
+    lines = [ln for ln in out.stdout.splitlines()
+             if ln.startswith("FOUR_SHARD_JSON ")]
+    assert lines, out.stderr[-2000:]
+    import json
+    return json.loads(lines[-1].split(" ", 1)[1])
+
+
+@pytest.mark.parametrize("arm", ["bucket", "dense"])
+def test_four_shard_planned_ids_match_single_device(four_shard_planned, arm):
+    """Ids bit-identical to single-device ``QueryEngine.query`` at
+    recall_target 0.9, re-ranked in blocks of 64 slots, with and without
+    a tracker."""
+    got = four_shard_planned["arms"][arm]
+    assert got["ids_equal"]
+    assert got["untracked_equal"]
+
+
+@pytest.mark.parametrize("arm", ["bucket", "dense"])
+def test_four_shard_planned_meets_the_contract(four_shard_planned, arm):
+    """recall@10 >= 0.9 against brute force at HIGHEST, and every score
+    the float64 inner product within 1e-5 of |q| |x|."""
+    got = four_shard_planned["arms"][arm]
+    assert got["recall"] >= 0.9
+    assert got["score_rel_err"] <= 1e-5
+
+
+def test_four_shard_calibration_is_bit_identical(four_shard_planned):
+    """Blocked and mesh-parallel calibration equal the unblocked table
+    fitted from the host's canonical order, bit for bit."""
+    cal = four_shard_planned["calibration"]
+    assert len(cal) == 4 and all(cal.values()), cal
+
+
+@pytest.mark.parametrize("arm", ["bucket", "dense"])
+def test_four_shard_owned_probes_sum_to_the_plan(four_shard_planned, arm):
+    """The shards' owned slots per query add up to the planned width."""
+    owned = four_shard_planned["arms"][arm]["owned"]
+    assert sum(owned) == pytest.approx(four_shard_planned["width"])
+    assert min(owned) >= 0
+
+
+def test_four_shard_rerank_never_holds_the_whole_plan(four_shard_planned):
+    """No (Q, width, d) array in the collective: the candidate rows are
+    gathered (Q, block, d) at a time. The guard itself sees the whole
+    plan's rows when the block is the whole plan."""
+    width, block = four_shard_planned["width"], four_shard_planned["block"]
+    assert width > 4 * block
+    assert four_shard_planned["qpd_block"] == [block]
+    assert max(four_shard_planned["qpd_plan"]) >= width // 4
+
+
 # -- jitted-collective cache --------------------------------------------------
 
 

@@ -104,6 +104,26 @@ def test_calibrate_from_order_matches_per_range_loop(q, n, m):
     np.testing.assert_array_equal(table.recall_range, want)
 
 
+@pytest.mark.parametrize("block", [None, 1, 24, 64])
+def test_blocked_calibration_equals_the_host_canonical_order(
+        calibrated, longtail_ds, monkeypatch, block):
+    """Device-counted truth positions, in any block of queries, fit the
+    table the unblocked host canonical order fits, bit for bit."""
+    if block is not None:  # None keeps the default block
+        monkeypatch.setattr(planner, "CAL_BLOCK", block)
+    queries = longtail_ds.queries[:64]
+    _, truth = topk.exact_mips(queries, calibrated.items, 10)
+    want = planner.calibrate_from_order(
+        planner.canonical_order(calibrated, queries),
+        np.asarray(calibrated.range_id), np.asarray(truth),
+        num_ranges=int(calibrated.table.shape[0]))
+    got = planner.calibrate(calibrated, queries, k=10)
+    for f in want._fields:
+        a, b = np.asarray(getattr(want, f)), np.asarray(getattr(got, f))
+        assert a.dtype == b.dtype, f
+        np.testing.assert_array_equal(a, b, err_msg=f)
+
+
 def test_target_validation():
     for bad in (0.0, -0.5, 1.5):
         with pytest.raises(ValueError, match="recall_target"):
