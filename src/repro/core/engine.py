@@ -17,6 +17,8 @@ Both engines realize Algorithm 2's probe order — the eq.-12 ranking of
     (kernels/bucket_probe.py). Work is O(B log B + num_probe) per query —
     sublinear in N whenever buckets collide (the paper's short-code
     regime), which is where the proven query complexity comes from.
+    Under per-range budgets only the S' buckets of the budgeted ranges
+    are sorted and walked.
 
 Canonical candidate order (shared by both engines): ascending
 ``(rank[j, l], CSR position)``. All items in a bucket share a rank; the
@@ -90,16 +92,57 @@ def _default_match(buckets: BucketIndex, impl: str):
         q_codes, codes, buckets.hash_bits, impl=impl)
 
 
+def _walked_spans(bucket_bounds: Sequence[int], budgets: Sequence[int]
+                  ) -> Tuple[Tuple[int, int], ...]:
+    """Directory rows ``[lo, hi)`` the planned walk visits: the buckets of
+    every range with a nonzero (effective) budget, adjacent ranges merged
+    into one span, in ascending directory order."""
+    spans = []
+    for j, b in enumerate(budgets):
+        lo, hi = bucket_bounds[j], bucket_bounds[j + 1]
+        if b <= 0 or lo == hi:
+            continue
+        if spans and spans[-1][1] == lo:
+            spans[-1] = (spans[-1][0], hi)
+        else:
+            spans.append((lo, hi))
+    return tuple(spans)
+
+
+def walked_buckets(bucket_bounds: Sequence[int],
+                   budgets: Sequence[int]) -> int:
+    """S', the directory buckets the planned walk visits under
+    ``budgets`` (B when every range is budgeted). A budget on a range with
+    no items walks nothing, so raw and effective budgets give one S'."""
+    return sum(hi - lo for lo, hi in _walked_spans(bucket_bounds, budgets))
+
+
+def _walked(a: jax.Array, spans: Sequence[Tuple[int, int]],
+            shift: int = 0) -> jax.Array:
+    """The walked directory columns of ``a`` (last axis), ``shift`` rows on
+    (``bucket_start`` holds B + 1 offsets): one static slice per span."""
+    parts = [a[..., lo + shift:hi + shift] for lo, hi in spans]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+
+
 def _directory_order(buckets: BucketIndex, q_codes: jax.Array,
-                     match_fn, tracker) -> jax.Array:
-    """(Q, B) probe-ordered bucket indices: directory match -> per-bucket
-    rank -> stable argsort (ties break by CSR bucket position). The shared
-    front half of every bucket-store traversal (staged, planned, fused)."""
+                     match_fn, tracker,
+                     spans: Optional[Sequence[Tuple[int, int]]] = None
+                     ) -> jax.Array:
+    """(Q, S) probe order of the directory buckets in ``spans`` (all B by
+    default), as positions among them: directory match -> per-bucket rank
+    -> stable argsort (ties break by CSR bucket position). The match
+    scores the whole directory; only the S walked columns are ranked and
+    sorted. A stable argsort of a subset of columns, kept in ascending
+    directory position, is the full order restricted to that subset, ties
+    included. The front half of every bucket-store traversal."""
+    if spans is None:
+        spans = ((0, buckets.num_buckets),)
     with span_or_null(tracker, "repro.engine.directory_match") as sp:
-        matches = match_fn(q_codes, buckets.bucket_code)         # (Q, B)
-        bucket_rank = buckets.rank[buckets.bucket_rid[None, :], matches]
-        return sp.sync(
-            jnp.argsort(bucket_rank, axis=-1, stable=True))      # (Q, B)
+        matches = _walked(match_fn(q_codes, buckets.bucket_code), spans)
+        rid = _walked(buckets.bucket_rid, spans)
+        return sp.sync(jnp.argsort(buckets.rank[rid[None, :], matches],
+                                   axis=-1, stable=True))        # (Q, S)
 
 
 def _probe_runs(buckets: BucketIndex, order: jax.Array,
@@ -118,16 +161,24 @@ def _probe_runs(buckets: BucketIndex, order: jax.Array,
 
 
 def _planned_runs(buckets: BucketIndex, order: jax.Array,
-                  budgets: Sequence[int], tracker=None
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """(cum (Q, B+1), starts (Q, B)) CSR runs realizing per-range budgets:
-    each probe-ordered bucket takes what is left of its range's budget
-    (zero-take buckets contribute empty runs)."""
-    sizes_o = (buckets.bucket_start[1:] - buckets.bucket_start[:-1])[order]
-    starts = buckets.bucket_start[:-1][order]
-    rid_o = buckets.bucket_rid[order]
+                  budgets: Sequence[int], spans: Sequence[Tuple[int, int]],
+                  tracker=None) -> Tuple[jax.Array, jax.Array]:
+    """(cum (Q, S'+1), starts (Q, S')) CSR runs realizing per-range
+    budgets over the walked columns in ``order``: each probe-ordered
+    bucket takes what is left of its range's budget. A bucket's take
+    depends only on the same-range buckets probed before it, so these are
+    the full directory walk's runs without its zero-take runs."""
+    # planned_take sees only the budgeted ranges, numbered 0..R'-1
+    ranges = [j for j, b in enumerate(budgets) if b > 0]
+    local = np.zeros((len(budgets),), np.int32)
+    local[ranges] = np.arange(len(ranges), dtype=np.int32)
+    rid_o = jnp.asarray(local)[_walked(buckets.bucket_rid, spans)][order]
+    start = _walked(buckets.bucket_start, spans)
+    sizes_o = (_walked(buckets.bucket_start, spans, 1) - start)[order]
+    starts = start[order]
     with span_or_null(tracker, "repro.engine.planned_take") as sp:
-        take = sp.sync(planned_take(rid_o, sizes_o, budgets))
+        take = sp.sync(planned_take(rid_o, sizes_o,
+                                    tuple(budgets[j] for j in ranges)))
     cum = jnp.concatenate(
         [jnp.zeros((order.shape[0], 1), jnp.int32),
          jnp.cumsum(take, axis=-1, dtype=jnp.int32)], axis=-1)
@@ -192,6 +243,17 @@ def bucket_range_counts(buckets: BucketIndex) -> np.ndarray:
         minlength=buckets.rank.shape[0]).astype(np.int64)
 
 
+def bucket_range_bounds(buckets: BucketIndex) -> Tuple[int, ...]:
+    """(R+1,) directory offsets of each range's buckets (host): the store
+    sorts buckets by range first, so range j owns directory rows
+    ``[bounds[j], bounds[j+1])``."""
+    rid = np.asarray(jax.device_get(buckets.bucket_rid))
+    if np.any(rid[1:] < rid[:-1]):
+        raise ValueError("bucket directory is not sorted by range id")
+    return tuple(int(b) for b in np.searchsorted(
+        rid, np.arange(buckets.num_ranges + 1), side="left"))
+
+
 def range_cum_before(rid_o: jax.Array, sizes_o: jax.Array,
                      num_ranges: int) -> jax.Array:
     """(Q, B) cumulative same-range sizes before each probe-ordered slot
@@ -226,25 +288,30 @@ def planned_bucket_candidates(buckets: BucketIndex, q_codes: jax.Array,
                               budgets: Sequence[int], *,
                               impl: str = "auto", match_fn=None,
                               range_counts: Optional[np.ndarray] = None,
+                              bucket_bounds: Optional[Sequence[int]] = None,
                               tracker=None) -> jax.Array:
     """(Q, sum_j min(b_j, n_j)) candidates under per-range probe budgets
     (DESIGN.md §12): for each range j, the first ``min(b_j, n_j)`` items
     of range j in canonical ``(rank, CSR position)`` order, emitted in
-    global canonical order. The directory walk computes, per bucket, how
-    much of its range's budget is left — zero-take buckets cost nothing
-    in the segmented gather. Pass ``range_counts`` (see
-    :func:`bucket_range_counts`) to skip the per-call host sync."""
+    global canonical order. The walk orders only the directory buckets of
+    ranges with a nonzero budget and computes, per bucket, how much of its
+    range's budget is left. Pass ``range_counts`` and ``bucket_bounds``
+    (see :func:`bucket_range_counts`, :func:`bucket_range_bounds`) to skip
+    the per-call host syncs."""
     if range_counts is None:
         range_counts = bucket_range_counts(buckets)
+    if bucket_bounds is None:
+        bucket_bounds = bucket_range_bounds(buckets)
     budgets, total = check_budgets(budgets, range_counts)
     if match_fn is None:
         match_fn = _default_match(buckets, impl)
-    order = _directory_order(buckets, q_codes, match_fn, tracker)
+    spans = _walked_spans(bucket_bounds, budgets)
+    order = _directory_order(buckets, q_codes, match_fn, tracker, spans)
     with span_or_null(tracker, "repro.engine.segmented_gather") as sp:
         # every query's takes sum to exactly ``total`` (each range always
         # contributes its full effective budget), so no covering run is
         # needed
-        cum, starts = _planned_runs(buckets, order, budgets, tracker)
+        cum, starts = _planned_runs(buckets, order, budgets, spans, tracker)
         csr_pos = ops.bucket_gather(cum, starts, total, impl=impl)
         return sp.sync(buckets.item_ids[csr_pos])
 
@@ -257,6 +324,7 @@ def fused_bucket_query(buckets: BucketIndex, q_codes: jax.Array,
                        scale: Optional[jax.Array] = None,
                        impl: str = "auto", match_fn=None,
                        range_counts: Optional[np.ndarray] = None,
+                       bucket_bounds: Optional[Sequence[int]] = None,
                        tracker=None) -> Tuple[jax.Array, jax.Array, int]:
     """Single-pass fused traversal + re-rank (DESIGN.md §17): directory
     match, then ONE kernel dispatch covering run expansion, phase-1
@@ -274,15 +342,20 @@ def fused_bucket_query(buckets: BucketIndex, q_codes: jax.Array,
         if range_counts is None:
             range_counts = bucket_range_counts(buckets)
         budgets, total = check_budgets(budgets, range_counts)
+        if bucket_bounds is None:
+            bucket_bounds = bucket_range_bounds(buckets)
+        spans = _walked_spans(bucket_bounds, budgets)
+        order = _directory_order(buckets, q_codes, match_fn, tracker, spans)
     else:
         total = int(num_probe)
         if not 0 < total <= buckets.num_items:
             raise ValueError(f"num_probe={total} outside "
                              f"(0, N={buckets.num_items}]")
-    order = _directory_order(buckets, q_codes, match_fn, tracker)
+        order = _directory_order(buckets, q_codes, match_fn, tracker)
     with span_or_null(tracker, "repro.engine.fused_query") as sp:
         if budgets is not None:
-            cum, starts = _planned_runs(buckets, order, budgets, tracker)
+            cum, starts = _planned_runs(buckets, order, budgets, spans,
+                                        tracker)
         else:
             cum, starts = _probe_runs(buckets, order, total)
         vals, pos = ops.fused_query(queries, cum, starts, items_csr,
@@ -538,6 +611,7 @@ class QueryEngine:
         self.quantized = quantized
         self.tracker = resolve_tracker(tracker)
         self._range_counts_cache = None
+        self._bucket_bounds_cache = None
         self._fused_cache = None
 
     @property
@@ -570,6 +644,14 @@ class QueryEngine:
         return self._range_counts_cache
 
     @property
+    def _bucket_bounds(self) -> Tuple[int, ...]:
+        """Per-range directory offsets (host, computed once — the planned
+        bucket walk slices the budgeted ranges' buckets by them)."""
+        if self._bucket_bounds_cache is None:
+            self._bucket_bounds_cache = bucket_range_bounds(self.buckets)
+        return self._bucket_bounds_cache
+
+    @property
     def _match_fn(self):
         """Family-aware match counter; None keeps the packed default."""
         fam = getattr(self.index, "family", None)
@@ -600,7 +682,8 @@ class QueryEngine:
                 return planned_bucket_candidates(
                     self.buckets, q_codes, budgets, impl=self.impl,
                     match_fn=self._match_fn,
-                    range_counts=self._range_counts, tracker=tr)
+                    range_counts=self._range_counts,
+                    bucket_bounds=self._bucket_bounds, tracker=tr)
             return planned_dense_candidates(
                 self.buckets, q_codes, self.index.codes, self._range_id,
                 budgets, impl=self.impl, match_fn=self._match_fn,
@@ -652,7 +735,8 @@ class QueryEngine:
                     num_probe=num_probe, budgets=budgets,
                     payload=payload, scale=scale, impl=self.impl,
                     match_fn=self._match_fn,
-                    range_counts=self._range_counts, tracker=tr)
+                    range_counts=self._range_counts,
+                    bucket_bounds=self._bucket_bounds, tracker=tr)
             else:
                 cand = self.candidates(queries, num_probe, budgets=budgets)
                 if not 0 < int(k) <= cand.shape[1]:
@@ -664,6 +748,13 @@ class QueryEngine:
         if tr is not None:
             tr.count("repro.engine.queries", queries.shape[0])
             tr.observe("repro.engine.probe_width", width)
+            if self.engine in ("bucket", "fused"):
+                # directory buckets ordered this batch: the budgeted
+                # ranges' on the planned walk, all B on the global one
+                walked = self.buckets.num_buckets
+                if budgets is not None:
+                    walked = walked_buckets(self._bucket_bounds, budgets)
+                tr.observe("repro.engine.walked_buckets", walked)
             if budgets is not None:
                 for j, b in enumerate(budgets):
                     tr.observe(f"repro.engine.probes_used.range{j}", b)

@@ -108,7 +108,8 @@ def test_bucket_match_directory(one_chip):
 
 @pytest.mark.parametrize("runs,slots", [
     (N // 100, N // 100),      # num_probe = N/100: S = min(B, P) runs
-    (B, 1 << 15),              # planned path: S = B runs, ~3% of N
+    (B, 1 << 15),              # planned walk, every range budgeted: S = B
+    (38, 1 << 15),             # planned walk, one range budgeted: S' runs
 ])
 def test_bucket_gather(one_chip, runs, slots):
     _compile(functools.partial(ops.bucket_gather, num_probe=slots,
